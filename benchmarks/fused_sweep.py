@@ -57,7 +57,7 @@ def _make_ops(n, D, q, sigma, seed=0):
 
 
 def _subjaxprs(params):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)

@@ -14,7 +14,10 @@ import sys
 
 import jax
 
+from repro import compile_cache
+
 jax.config.update("jax_enable_x64", True)
+compile_cache.configure()
 
 
 def main() -> None:

@@ -29,9 +29,11 @@ from .backfitting import DimOps, SolveConfig, solve_mhat, mhat_matvec
 from .band_inverse import variance_band
 from .banded import Banded, add, logdet, matvec, scale, solve, transpose
 from .kernel_packets import gkp_factors, kp_factors, phi_at, phi_grad_at
+from .ordering import argsort_rows, inverse_perm, separate_ties
 from .stochastic import logdet_taylor, rademacher_rows
 
-__all__ = ["GPConfig", "AdditiveGP", "fit", "with_capacity", "mean_caches",
+__all__ = ["GPConfig", "AdditiveGP", "fit", "resolve_config", "with_capacity",
+           "mean_caches",
            "posterior_caches", "posterior_mean", "posterior_var",
            "log_likelihood", "mll_gradients", "fit_hyperparams", "TIE_EPS"]
 
@@ -61,8 +63,9 @@ class GPConfig:
     solver: str = "pcg"  # backfitting method for Mhat^{-1}
     solver_iters: int = 50
     pivot: bool = False
-    # banded-algebra backend: "auto" (pallas on TPU, jax elsewhere) | "jax" |
-    # "pallas"; threaded through every matvec/solve/logdet via kernels.ops
+    # banded-algebra backend: "auto" (64-bit data -> jax; else pallas on TPU,
+    # jax elsewhere) | "jax" | "pallas"; threaded through every
+    # matvec/solve/logdet via kernels.ops
     backend: str = "auto"
     # pallas solve/logdet kernel: "auto" (block CR when lo == hi, else LU) |
     # "lu" | "cr"; also settable process-wide via REPRO_SOLVE_ALG
@@ -211,7 +214,8 @@ def fit(config: GPConfig, X: jax.Array, Y: jax.Array, omega: jax.Array, sigma,
     """Build all sparse factors and posterior caches — O(n log n).
 
     The banded-algebra backend is resolved here (config "auto" -> concrete
-    "jax"/"pallas" via the process default / REPRO_BACKEND / platform) and
+    "jax"/"pallas" via the process default / REPRO_BACKEND / the backend
+    rule of ``kernels.ops``: 64-bit data -> "jax", else by platform) and
     baked into the returned GP, so the jit cache keys on the *resolved*
     backend and later ``set_backend`` calls can't silently hit a stale trace.
     The solve algorithm gets the same treatment: a config-level "auto"
@@ -229,23 +233,29 @@ def fit(config: GPConfig, X: jax.Array, Y: jax.Array, omega: jax.Array, sigma,
     then mutate it in place with zero recompilation until the capacity is
     exhausted.
     """
-    from ..kernels import ops as _kops
-
-    config = dataclasses.replace(
-        config,
-        backend=_kops.resolve_backend(config.backend),
-        solve_alg=(config.solve_alg if config.solve_alg != "auto"
-                   else _kops.get_solve_alg()),
-        fused=(config.fused if config.fused != "auto"
-               else _kops.get_fused()),
-        precond=_kops.resolve_precond(config.precond, q=config.q,
-                                      n=X.shape[0]),
-        gband=_kops.resolve_gband(config.gband),
-        health=_kops.resolve_health(config.health))
+    config = resolve_config(config, X.shape[0], jnp.result_type(X, Y))
     gp = _fit_impl(config, X, Y, omega, sigma)
     if capacity is not None:
         gp = with_capacity(gp, capacity)
     return gp
+
+
+def resolve_config(config: GPConfig, n: int, dtype) -> GPConfig:
+    """Bake every "auto" mode of ``config`` for an ``n``-point fit computed
+    in ``dtype``. The backend follows the rule in ``kernels.ops`` (64-bit
+    -> "jax"; else "pallas" on TPU); shared by ``fit`` and ``fleet_fit``."""
+    from ..kernels import ops as _kops
+
+    return dataclasses.replace(
+        config,
+        backend=_kops.resolve_backend(config.backend, dtype),
+        solve_alg=(config.solve_alg if config.solve_alg != "auto"
+                   else _kops.get_solve_alg()),
+        fused=(config.fused if config.fused != "auto"
+               else _kops.get_fused()),
+        precond=_kops.resolve_precond(config.precond, q=config.q, n=n),
+        gband=_kops.resolve_gband(config.gband),
+        health=_kops.resolve_health(config.health))
 
 
 def _pad_rows(x: jax.Array, capacity: int, axis: int) -> jax.Array:
@@ -391,16 +401,14 @@ def _fit_impl(config: GPConfig, X: jax.Array, Y: jax.Array, omega: jax.Array,
     q = config.q
     n, D = X.shape
     sigma = jnp.asarray(sigma, X.dtype)
-    sort_idx = jnp.argsort(X.T, axis=1)  # (D, n)
+    sort_idx = argsort_rows(X.T)  # (D, n)
     xs = jnp.take_along_axis(X.T, sort_idx, axis=1)
-    rank_idx = jnp.argsort(sort_idx, axis=1)
+    rank_idx = inverse_perm(sort_idx)
     # KP construction (Thm 3) requires distinct sorted points; BO proposals
     # clipped to the box boundary can create exact ties. Separate ties by a
     # span-relative epsilon (preserves order; perturbation ~1e-9 of range).
     span = xs[:, -1:] - xs[:, :1] + 1.0
-    gaps = jnp.diff(xs, axis=1)
-    bump = jnp.cumsum(jnp.where(gaps <= 0, span * TIE_EPS, 0.0), axis=1)
-    xs = xs.at[:, 1:].add(bump)
+    xs = separate_ties(xs, span * TIE_EPS)
     A, Phi, B, Psi = _build_factors(q, omega, xs)
     SAPhi = add(scale(A, sigma**2), Phi)
     ops = DimOps(A=A, Phi=Phi, SAPhi=SAPhi, sort_idx=sort_idx, rank_idx=rank_idx,

@@ -140,22 +140,11 @@ class DimOps:
         return self.sort_idx.shape[1]
 
     def to_sorted(self, u: jax.Array) -> jax.Array:
-        """(D, n, B) original order -> sorted order per dim.
-
-        Under capacity padding the gather uses canonical (identity-tail)
-        permutations and re-zeros the tail, so poisoned pad slots in either
-        the indices or the state can never leak into reductions.
-        """
-        idx = canonical_perm(self.sort_idx, self.n_active)
-        idx = idx[..., None] if u.ndim == 3 else idx
-        out = jnp.take_along_axis(u, jnp.broadcast_to(idx, u.shape), axis=1)
-        return mask_rows(out, self.n_active, axis=1)
+        """(D, n, B) original order -> sorted order per dim."""
+        return _permute_rows(u, self.sort_idx, self.n_active)
 
     def from_sorted(self, u: jax.Array) -> jax.Array:
-        idx = canonical_perm(self.rank_idx, self.n_active)
-        idx = idx[..., None] if u.ndim == 3 else idx
-        out = jnp.take_along_axis(u, jnp.broadcast_to(idx, u.shape), axis=1)
-        return mask_rows(out, self.n_active, axis=1)
+        return _permute_rows(u, self.rank_idx, self.n_active)
 
     def khat_inv_mv(self, u: jax.Array, pivot: bool = False,
                     backend: str | None = None,
@@ -183,6 +172,21 @@ class DimOps:
         w = self.sigma2 * solve(self.SAPhi, matvec(self.Phi, rs, backend=backend),
                                 pivot=pivot, backend=backend, alg=alg)
         return self.from_sorted(w)
+
+
+def _permute_rows(u: jax.Array, idx: jax.Array, n_active) -> jax.Array:
+    """``u[d, idx[d, i], ...]`` for (D, n) or (D, n, B) ``u``.
+
+    The index keeps a size-1 trailing axis, so the gather moves whole rows:
+    broadcasting it over ``B`` first makes an element gather, whose TPU
+    compile grows with n (~16 s at n=16000, against 0.3 s for this form).
+    Under capacity padding the gather uses canonical (identity-tail)
+    permutations and re-zeros the tail, so poisoned pad slots in either the
+    indices or the state can never leak into reductions.
+    """
+    idx = canonical_perm(idx, n_active)
+    idx = idx[..., None] if u.ndim == 3 else idx
+    return mask_rows(jnp.take_along_axis(u, idx, axis=1), n_active, axis=1)
 
 
 def mhat_matvec(ops: DimOps, u: jax.Array, pivot: bool = False,
@@ -233,7 +237,8 @@ def _maybe_fused(ops: DimOps, v: jax.Array, cfg: SolveConfig):
         ops.Phi.data, ops.SAPhi.data, ops.sort_idx, ops.rank_idx, ops.sigma2,
         w_p=ops.Phi.lo, w_s=ops.SAPhi.lo,
         a=ops.A.data if need_a else None, w_a=ops.A.lo, pivot=cfg.pivot,
-        interpret=not _kops.on_tpu(), dtype=v.dtype, n_active=ops.n_active)
+        interpret=_kops.interpret_kernels(), dtype=v.dtype,
+        n_active=ops.n_active)
 
 
 def _kinv0(ops: DimOps, x0: jax.Array, cfg: SolveConfig) -> jax.Array:
@@ -300,12 +305,12 @@ def _gauss_seidel(ops: DimOps, v: jax.Array, cfg: SolveConfig,
         saphi = Banded(ops.SAPhi.data[d], ops.SAPhi.lo, ops.SAPhi.hi, na)
         phi = Banded(ops.Phi.data[d], ops.Phi.lo, ops.Phi.hi, na)
         idx = canonical_perm(ops.sort_idx[d], na)[:, None]
-        rs = jnp.take_along_axis(r_d, jnp.broadcast_to(idx, r_d.shape), axis=0)
+        rs = jnp.take_along_axis(r_d, idx, axis=0)
         w = ops.sigma2 * solve(saphi, matvec(phi, rs, backend=cfg.backend),
                                pivot=cfg.pivot, backend=cfg.backend,
                                alg=cfg.alg)
         ridx = canonical_perm(ops.rank_idx[d], na)[:, None]
-        out = jnp.take_along_axis(w, jnp.broadcast_to(ridx, w.shape), axis=0)
+        out = jnp.take_along_axis(w, ridx, axis=0)
         return mask_rows(out, na, axis=0)
 
     def sweep(vt, instrument=False):

@@ -43,7 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .additive_gp import (AdditiveGP, GPConfig, _fit_impl, _with_capacity_impl,
-                          posterior_mean, posterior_var, with_capacity)
+                          posterior_mean, posterior_var, resolve_config,
+                          with_capacity)
 from .bayesopt import acquisition_stats
 
 __all__ = ["GPFleet", "stack_gps", "fleet_fit", "fleet_posterior_mean",
@@ -170,22 +171,11 @@ def fleet_fit(config: GPConfig, X, Y, omega, sigma,
     Backend / solve-alg / fused resolution happens once here, exactly like
     ``fit``.
     """
-    from ..kernels import ops as _kops
-
     X = jnp.asarray(X)
     T, n, D = X.shape
     if capacity < n:
         raise ValueError(f"capacity {capacity} < n {n}")
-    config = dataclasses.replace(
-        config,
-        backend=_kops.resolve_backend(config.backend),
-        solve_alg=(config.solve_alg if config.solve_alg != "auto"
-                   else _kops.get_solve_alg()),
-        fused=(config.fused if config.fused != "auto"
-               else _kops.get_fused()),
-        precond=_kops.resolve_precond(config.precond, q=config.q, n=n),
-        gband=_kops.resolve_gband(config.gband),
-        health=_kops.resolve_health(config.health))
+    config = resolve_config(config, n, jnp.result_type(X, jnp.asarray(Y)))
     sigma = jnp.broadcast_to(jnp.asarray(sigma, X.dtype), (T,))
     omega = jnp.broadcast_to(jnp.asarray(omega, X.dtype), (T, D))
     return GPFleet(gp=_fleet_fit_impl(config, X, jnp.asarray(Y), omega, sigma,

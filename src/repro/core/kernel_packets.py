@@ -10,10 +10,13 @@ with q = nu - 1/2. ``B`` is exactly the Matérn-(nu+1) KP coefficient matrix
 (Appendix C), so one construction routine serves both.
 
 TPU adaptation (vs the paper's sequential MATLAB loop): all n window systems
-are solved at once as a vmapped batch of tiny SVD null-space problems, with
-per-window centering + column scaling (shift/scale invariance of Eq. (9)) so
-``exp(omega x)`` never overflows. Construction cost O(n * (2q+3)^3) fully
-parallel, instead of a length-n sequential loop.
+are solved at once, with per-window centering + column scaling (shift/scale
+invariance of Eq. (9)) so ``exp(omega x)`` never overflows. Each window's
+null vector comes from a rolled Householder QR computed elementwise over the
+rows — lane-dense on the TPU, where a batched SVD of (2q+2, 2q+3) matrices
+pads every matrix to an (8, 128) tile and compiles to a huge program.
+Construction cost O(n * (2q+3)^3) fully parallel, instead of a length-n
+sequential loop.
 """
 from __future__ import annotations
 
@@ -21,6 +24,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import matern as mk
 from .banded import Banded, mask_band
@@ -65,53 +69,92 @@ def _kp_row_inputs(n, q: int, rows: jax.Array, clip_n: int | None = None):
     return j_idx, valid, primary_sign, aux_sign, n_aux
 
 
-def _kp_build_row(q: int, omega, xrow, vrow, psign, asign, naux):
-    """One KP coefficient row from its window points + Algorithm-2 category."""
+def _null_vector(E):
+    """Unit right null vectors of m x (m+1) matrices ``E`` (m, m+1, r).
+
+    The last column of Q in a Householder QR of ``E^T``: ``E^T = Q R`` with
+    R's last row zero gives ``E q = R^T Q^T q = 0`` at any rank, and the
+    reflections are backward stable like an SVD null vector. The batch
+    ``r`` trails, so every step is an elementwise op on lane-dense batch
+    vectors (no tiny trailing matrix dims for the TPU's (8, 128) tiling to
+    pad), and ``fori_loop``s over the m reflections keep the program one
+    small loop body: under XLA's float64 emulation on the TPU an unrolled
+    factorization costs minutes of compile.
+    """
+    m, P = E.shape[0], E.shape[1]
+    idx = jnp.arange(P)[:, None]
+    Et = jnp.swapaxes(E, 0, 1)  # (P, m, r)
+
+    def reflect(k, carry):
+        A, U = carry
+        x = jnp.where(idx >= k, jax.lax.dynamic_index_in_dim(
+            A, k, axis=1, keepdims=False), 0.0)
+        xk = jax.lax.dynamic_index_in_dim(x, k, axis=0, keepdims=False)
+        alpha = -jnp.where(xk >= 0, 1.0, -1.0) * jnp.sqrt(
+            jnp.sum(x * x, axis=0))
+        v = jnp.where(idx == k, x - alpha, x)
+        vv = jnp.sum(v * v, axis=0)
+        u = v * jnp.where(vv > 0, jnp.sqrt(2.0 / jnp.where(vv > 0, vv, 1.0)),
+                          0.0)  # H_k = I - u u^T
+        A = A - u[:, None] * jnp.sum(u[:, None] * A, axis=0)
+        return A, jax.lax.dynamic_update_index_in_dim(U, u, k, axis=0)
+
+    U = jnp.zeros((m,) + Et[:, 0].shape, E.dtype)
+    _, U = jax.lax.fori_loop(0, m, reflect, (Et, U))
+
+    def apply(i, y):  # q = H_0 ... H_{m-1} e_{P-1}
+        u = jax.lax.dynamic_index_in_dim(U, m - 1 - i, axis=0, keepdims=False)
+        return y - u * jnp.sum(u * y, axis=0)
+
+    e_last = jnp.broadcast_to((idx == P - 1).astype(E.dtype), Et[:, 0].shape)
+    return jax.lax.fori_loop(0, m, apply, e_last)
+
+
+def _kp_build_rows(q: int, omega, xw, vw, psign, asign, naux):
+    """KP coefficient rows from window points + Algorithm-2 categories.
+
+    ``xw``/``vw``: (P, r) window points / validity with the row batch on the
+    trailing axis; signs and ``naux``: (r,). Returns (r, P). The window
+    system ``E a = 0`` (Eq. (9)) is formed entry by entry and solved for its
+    null vector, all elementwise over the rows.
+    """
     P = 2 * q + 3  # window size (central rows)
+    dtype = xw.dtype
     # center & scale for conditioning (shift/scale invariance of Eq. (9))
-    c = jnp.sum(jnp.where(vrow, xrow, 0.0)) / jnp.maximum(jnp.sum(vrow), 1)
-    xt = jnp.where(vrow, xrow - c, 0.0)
-    s = jnp.maximum(jnp.max(jnp.abs(xt)), 1e-30)
+    c = jnp.sum(jnp.where(vw, xw, 0.0), axis=0) / jnp.maximum(
+        jnp.sum(vw, axis=0), 1)
+    xt = jnp.where(vw, xw - c, 0.0)
+    s = jnp.maximum(jnp.max(jnp.abs(xt), axis=0), 1e-30)
     xh = xt / s
     # column scaling to bound exp terms: factor exp(-omega |xt|)
     col_log = -omega * jnp.abs(xt)
-    ls = jnp.arange(q + 1)[:, None]  # (q+1, 1)
-    # primary block rows l=0..q, sign psign
-    prim = (xh[None, :] ** ls) * jnp.exp(psign * omega * xt[None, :] + col_log)
-    # aux block rows r=0..q, sign asign (mask to first naux rows)
-    aux = (xh[None, :] ** ls) * jnp.exp(asign * omega * xt[None, :] + col_log)
-    aux_valid = jnp.arange(q + 1)[:, None] < naux
-    aux = jnp.where(aux_valid, aux, 0.0)
-    E = jnp.concatenate([prim, aux], axis=0)  # (2q+2, P)
-    # invalid columns: pin a_j = 0 by pairing each masked aux row with a
-    # unit row selecting one invalid column.
-    inv_cols = ~vrow  # (P,)
-    # rank of invalid columns among themselves
-    inv_rank = jnp.cumsum(inv_cols) - 1  # index among invalid
-    pin_rows = jnp.zeros((q + 1, P), E.dtype)
-    # aux row (q+1+r) is masked for r >= naux; use masked slot index r-naux... we
-    # instead build: for each invalid column p, add unit row at slot inv_rank[p].
-    pin_rows = pin_rows.at[jnp.clip(inv_rank, 0, q), jnp.arange(P)].add(
-        jnp.where(inv_cols, 1.0, 0.0)
-    )
-    aux_slots = jnp.arange(q + 1)[:, None] >= naux  # masked aux slots
-    # place pin rows into masked aux slots: slot r (>= naux) takes pin row (r - naux)
-    shift = jnp.arange(q + 1) - naux
-    pin_for_slot = jnp.where(
-        (shift >= 0)[:, None] & aux_slots,
-        pin_rows[jnp.clip(shift, 0, q)],
-        0.0,
-    )
-    E = E.at[q + 1 :].add(pin_for_slot)
-    # null space via SVD (smallest right singular vector)
-    _, _, vt = jnp.linalg.svd(E, full_matrices=True)
-    a_tilde = vt[-1]
+    ep = jnp.exp(psign * omega * xt + col_log)  # (P, r)
+    ea = jnp.exp(asign * omega * xt + col_log)
+    # invalid columns pin a_p = 0: masked aux slot r (r >= naux) takes the
+    # unit row of the invalid column whose rank among invalid ones is r-naux
+    inv = ~vw
+    inv_rank = jnp.clip(jnp.cumsum(inv, axis=0) - 1, 0, q)
+    pw = [jnp.ones_like(xh)]
+    for _ in range(q):
+        pw.append(pw[-1] * xh)
+    pw = jnp.stack(pw)  # (q+1, P, r): xh ** l
+    slot = jnp.arange(q + 1)[:, None, None]
+    live = slot < naux
+    pin = ~live & inv & (inv_rank == slot - naux)
+    E = jnp.concatenate([pw * ep, jnp.where(live, pw * ea, 0.0)
+                         + pin.astype(dtype)])  # (2q+2, P, r)
+    # a row that is both a left and a right row (n <= 2q+1) has more live
+    # equations than free coefficients and all of K's row inside Phi's band:
+    # it keeps the unit row e_i (every other coefficient pinned)
+    degenerate = (q + 1 + naux >= jnp.sum(vw, axis=0)) & vw[q + 1]
+    unit = np.delete(np.eye(P), q + 1, axis=0)[..., None]  # (2q+2, P, 1)
+    E = jnp.where(degenerate, jnp.asarray(unit, dtype), E)
+    a = _null_vector(E)  # (P, r)
     # undo column scaling
-    a = a_tilde * jnp.exp(col_log)
-    a = jnp.where(vrow, a, 0.0)
-    a = a / jnp.maximum(jnp.linalg.norm(a), 1e-30)
+    a = jnp.where(vw, a * jnp.exp(col_log), 0.0)
+    a = a / jnp.maximum(jnp.sqrt(jnp.sum(a * a, axis=0)), 1e-30)
     sign = jnp.sign(a[q + 1]) + (a[q + 1] == 0)
-    return a * sign
+    return (a * sign).T
 
 
 @partial(jax.jit, static_argnums=0)
@@ -130,8 +173,7 @@ def kp_coefficient_rows(q: int, omega, xs: jax.Array, rows: jax.Array,
     na = n if n_active is None else n_active
     j_idx, valid, psign, asign, naux = _kp_row_inputs(na, q, rows, clip_n=n)
     xw = jnp.where(valid, xs[j_idx], 0.0)
-    return jax.vmap(partial(_kp_build_row, q, omega))(xw, valid, psign, asign,
-                                                      naux)
+    return _kp_build_rows(q, omega, xw.T, valid.T, psign, asign, naux)
 
 
 @partial(jax.jit, static_argnums=0)

@@ -22,6 +22,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ..masking import canonical_band
@@ -29,6 +30,9 @@ from ..masking import canonical_band
 __all__ = ["band_matmul_pallas"]
 
 DEF_BLOCK = 512
+# int32 block index: under x64 a literal 0 in an index map is int64, which
+# Mosaic refuses
+_I0 = np.int32(0)
 
 
 def _kernel(a_ref, bp_ref, bc_ref, bn_ref, o_ref, *, a_lo, a_hi, b_lo, b_hi,
@@ -38,11 +42,15 @@ def _kernel(a_ref, bp_ref, bc_ref, bn_ref, o_ref, *, a_lo, a_hi, b_lo, b_hi,
     a = a_ref[...]  # (block, wa)
     bb = jnp.concatenate([bp_ref[...], bc_ref[...], bn_ref[...]], axis=0)
     acc = jnp.zeros((block, lo + hi + 1), a.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
     for t in range(-a_lo, a_hi + 1):
-        rows = jax.lax.dynamic_slice_in_dim(bb, block + t, block, axis=0)
+        rows = bb[block + t : 2 * block + t]  # static shift
         a_col = a[:, a_lo + t][:, None]
-        # C[i, lo + t + s] += A[i, i+t] * B[i+t, (i+t)+s], s in [-b_lo, b_hi]
-        acc = acc.at[:, lo + t - b_lo : lo + t + b_hi + 1].add(a_col * rows)
+        # C[i, lo + t + s] += A[i, i+t] * B[i+t, (i+t)+s], s in [-b_lo, b_hi];
+        # lane-select instead of a lane-slice update (no scatter in Mosaic)
+        for s in range(-b_lo, b_hi + 1):
+            prod = a_col * rows[:, b_lo + s][:, None]
+            acc = acc + jnp.where(lane == lo + t + s, prod, 0.0)
     o_ref[...] = acc
 
 
@@ -51,7 +59,7 @@ def _kernel(a_ref, bp_ref, bc_ref, bn_ref, o_ref, *, a_lo, a_hi, b_lo, b_hi,
                                     "interpret"))
 def band_matmul_pallas(a_band: jax.Array, b_band: jax.Array,
                        a_lo: int, a_hi: int, b_lo: int, b_hi: int,
-                       block: int = DEF_BLOCK, interpret: bool = True,
+                       block: int = DEF_BLOCK, *, interpret: bool,
                        n_active=None):
     """a_band: (G, n, a_lo+a_hi+1), b_band: (G, n, b_lo+b_hi+1) ->
     C band (G, n, a_lo+b_lo+a_hi+b_hi+1).
@@ -83,12 +91,12 @@ def band_matmul_pallas(a_band: jax.Array, b_band: jax.Array,
                           block=block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block, wa), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((None, block, wb), lambda g, i: (g, i, 0)),      # prev
-            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 1, 0)),  # cur
-            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 2, 0)),  # next
+            pl.BlockSpec((None, block, wa), lambda g, i: (g, i, _I0)),
+            pl.BlockSpec((None, block, wb), lambda g, i: (g, i, _I0)),      # prev
+            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 1, _I0)),  # cur
+            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 2, _I0)),  # next
         ],
-        out_specs=pl.BlockSpec((None, block, wc), lambda g, i: (g, i, 0)),
+        out_specs=pl.BlockSpec((None, block, wc), lambda g, i: (g, i, _I0)),
         out_shape=jax.ShapeDtypeStruct((G, npad, wc), dtype),
         interpret=interpret,
     )(a_p, bz, bz, bz)

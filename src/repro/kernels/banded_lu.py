@@ -66,7 +66,8 @@ def _kernel(band_ref, rhs_ref, x_ref, ld_ref, u_ref, y_ref, xp_ref,
         u_ref[...] = band_ref[...]
         y_ref[...] = rhs_ref[...]
 
-    ld_ref[0, 0] = jnp.sum(jnp.log(jnp.abs(u_ref[lo : lo + n, 0])))
+    ld = jnp.sum(jnp.log(jnp.abs(u_ref[lo : lo + n, 0:1])))
+    ld_ref[...] = jnp.full(ld_ref.shape, ld, dtype)
 
     # --- back substitution (skipped for logdet-only calls) ------------------
     if not solve:
@@ -91,7 +92,7 @@ def _kernel(band_ref, rhs_ref, x_ref, ld_ref, u_ref, y_ref, xp_ref,
 
 @functools.partial(jax.jit, static_argnames=("lo", "hi", "interpret", "solve"))
 def banded_lu_pallas(band: jax.Array, rhs: jax.Array, lo: int, hi: int,
-                     interpret: bool = True, solve: bool = True,
+                     *, interpret: bool, solve: bool = True,
                      n_active=None):
     """band: (G, n, lo+hi+1) row-aligned; rhs: (G, n, B).
     Returns (x (G, n, B), logdet (G,)); 2-D inputs squeeze the G axis.
@@ -123,11 +124,13 @@ def banded_lu_pallas(band: jax.Array, rhs: jax.Array, lo: int, hi: int,
         ],
         out_specs=[
             pl.BlockSpec((None, n, B), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 1), lambda g: (g, 0)),
+            # (1, 1) trailing block == full trailing dims: the (8, 128)
+            # tiling rule holds for the per-item logdet
+            pl.BlockSpec((None, 1, 1), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, n, B), dtype),
-            jax.ShapeDtypeStruct((G, 1), dtype),
+            jax.ShapeDtypeStruct((G, 1, 1), dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((n + lo, hi + 1), dtype),   # U rows (+ identity padding)
@@ -136,11 +139,11 @@ def banded_lu_pallas(band: jax.Array, rhs: jax.Array, lo: int, hi: int,
         ],
         interpret=interpret,
     )(band.astype(dtype), rhs.astype(dtype))
-    ld = ld[:, 0]
+    ld = ld[:, 0, 0]
     return (x[0], ld[0]) if squeeze else (x, ld)
 
 
-def banded_solve_pallas(band, rhs, lo: int, hi: int, interpret: bool = True,
+def banded_solve_pallas(band, rhs, lo: int, hi: int, *, interpret: bool,
                         n_active=None):
     """Solve M x = rhs (no pivoting); rhs (G, n, B) or (n, B)."""
     x, _ = banded_lu_pallas(band, rhs, lo, hi, interpret=interpret,
@@ -148,7 +151,7 @@ def banded_solve_pallas(band, rhs, lo: int, hi: int, interpret: bool = True,
     return x
 
 
-def banded_logdet_pallas(band, lo: int, hi: int, interpret: bool = True,
+def banded_logdet_pallas(band, lo: int, hi: int, *, interpret: bool,
                          n_active=None):
     """log|det M| from the same elimination (width-1 dummy RHS, no back-sub)."""
     n = band.shape[-2]

@@ -19,6 +19,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ..masking import canonical_band, mask_rows
@@ -26,6 +27,9 @@ from ..masking import canonical_band, mask_rows
 __all__ = ["banded_matvec_pallas"]
 
 DEF_BLOCK = 512
+# int32 block index: under x64 a literal 0 in an index map is int64, which
+# Mosaic refuses
+_I0 = np.int32(0)
 
 
 def _kernel(band_ref, xp_ref, xc_ref, xn_ref, o_ref, *, lo, hi, block):
@@ -34,14 +38,14 @@ def _kernel(band_ref, xp_ref, xc_ref, xn_ref, o_ref, *, lo, hi, block):
     # xx: (3*block, B); row i of this tile reads xx[block + i + m]
     acc = jnp.zeros_like(o_ref)
     for m in range(-lo, hi + 1):
-        seg = jax.lax.dynamic_slice_in_dim(xx, block + m, block, axis=0)
+        seg = xx[block + m : 2 * block + m]  # static shift: Mosaic-lowerable
         acc = acc + band[:, lo + m][:, None] * seg
     o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("lo", "hi", "block", "interpret"))
 def banded_matvec_pallas(band: jax.Array, x: jax.Array, lo: int, hi: int,
-                         block: int = DEF_BLOCK, interpret: bool = True,
+                         block: int = DEF_BLOCK, *, interpret: bool,
                          n_active=None):
     """band: (G, n, lo+hi+1); x: (G, n, B) -> (G, n, B). n padded to `block`.
 
@@ -76,12 +80,12 @@ def banded_matvec_pallas(band: jax.Array, x: jax.Array, lo: int, hi: int,
         functools.partial(_kernel, lo=lo, hi=hi, block=block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block, w), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((None, block, B), lambda g, i: (g, i, 0)),      # prev
-            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 1, 0)),  # cur
-            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 2, 0)),  # next
+            pl.BlockSpec((None, block, w), lambda g, i: (g, i, _I0)),
+            pl.BlockSpec((None, block, B), lambda g, i: (g, i, _I0)),      # prev
+            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 1, _I0)),  # cur
+            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 2, _I0)),  # next
         ],
-        out_specs=pl.BlockSpec((None, block, B), lambda g, i: (g, i, 0)),
+        out_specs=pl.BlockSpec((None, block, B), lambda g, i: (g, i, _I0)),
         out_shape=jax.ShapeDtypeStruct((G, npad, B), dtype),
         interpret=interpret,
     )(band_p, xz, xz, xz)

@@ -180,13 +180,13 @@ def _kernel(band_ref, rhs_ref, x_ref, ld_ref, *, w, nb, steps, pivot, solve):
     x, ld = cr_solve_values(band_ref[0], rhs_ref[0], w=w, nb=nb, steps=steps,
                             pivot=pivot, solve=solve)
     x_ref[0] = x
-    ld_ref[0, 0] = ld
+    ld_ref[...] = jnp.full(ld_ref.shape, ld, ld_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("w", "pivot", "interpret", "solve"))
 def block_cr_pallas(band: jax.Array, rhs: jax.Array, w: int,
-                    pivot: bool = False, interpret: bool = True,
+                    pivot: bool = False, *, interpret: bool,
                     solve: bool = True, n_active=None):
     """band: (G, n, 2w+1) row-aligned, lo = hi = w; rhs: (G, n, B).
 
@@ -229,20 +229,22 @@ def block_cr_pallas(band: jax.Array, rhs: jax.Array, w: int,
         ],
         out_specs=[
             pl.BlockSpec((1, npad, B), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 1), lambda g: (g, 0)),
+            # (1, 1) trailing block == full trailing dims: the (8, 128)
+            # tiling rule holds for the per-item logdet
+            pl.BlockSpec((None, 1, 1), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((G, npad, B), dtype),
-            jax.ShapeDtypeStruct((G, 1), dtype),
+            jax.ShapeDtypeStruct((G, 1, 1), dtype),
         ],
         interpret=interpret,
     )(band_p, rhs_p)
-    x, ld = x[:, :n], ld[:, 0]
+    x, ld = x[:, :n], ld[:, 0, 0]
     return (x[0], ld[0]) if squeeze else (x, ld)
 
 
 def block_cr_solve_pallas(band, rhs, w: int, pivot: bool = False,
-                          interpret: bool = True, n_active=None):
+                          *, interpret: bool, n_active=None):
     """Solve M x = rhs by block cyclic reduction; rhs (G, n, B) or (n, B)."""
     x, _ = block_cr_pallas(band, rhs, w, pivot=pivot, interpret=interpret,
                            n_active=n_active)
@@ -250,7 +252,7 @@ def block_cr_solve_pallas(band, rhs, w: int, pivot: bool = False,
 
 
 def block_cr_logdet_pallas(band, w: int, pivot: bool = False,
-                           interpret: bool = True, n_active=None):
+                           *, interpret: bool, n_active=None):
     """log|det M| from the same elimination (width-1 dummy RHS, no back-sub)."""
     n = band.shape[-2]
     dummy = jnp.zeros(band.shape[:-2] + (n, 1), band.dtype)

@@ -186,7 +186,7 @@ def _jacobi_kernel(sig_ref, v_ref, vt_ref, phi_ref, saphi_ref, sort_ref,
                                              "interpret", "want_resid"))
 def fused_jacobi_iter_pallas(phi, saphi, sort_idx, rank_idx, sigma2, v, vt,
                              k=None, *, w_p: int, w_s: int, alpha: float,
-                             pivot: bool = False, interpret: bool = True,
+                             pivot: bool = False, interpret: bool,
                              want_resid: bool = False):
     """One damped block-Jacobi sweep; all operands pre-padded (D, npad, ...).
 
@@ -263,7 +263,7 @@ def _gs_kernel(sig_ref, v_ref, vt_ref, phi_ref, saphi_ref, sort_ref, rank_ref,
 def fused_gauss_seidel_iter_pallas(phi, saphi, sort_idx, rank_idx, sigma2, v,
                                    vt, *, w_p: int, w_s: int,
                                    pivot: bool = False,
-                                   interpret: bool = True,
+                                   interpret: bool,
                                    want_resid: bool = False):
     """One sequential-over-dims Gauss-Seidel sweep (pre-padded operands).
 
@@ -362,7 +362,7 @@ def _pcg_kernel(sig_ref, rz_ref, x_ref, r_ref, p_ref, a_ref, phi_ref,
                                              "interpret"))
 def fused_pcg_iter_pallas(a, phi, saphi, sort_idx, rank_idx, sigma2, x, r, p,
                           rz, *, w_a: int, w_p: int, w_s: int,
-                          pivot: bool = False, interpret: bool = True):
+                          pivot: bool = False, interpret: bool):
     """One PCG iteration on Mhat; returns ``(x, r, p, rz)`` updated.
 
     All array operands pre-padded (D, npad, ...); ``rz`` is the carried
@@ -428,7 +428,7 @@ class FusedSweep:
 
     def __init__(self, phi, saphi, sort_idx, rank_idx, sigma2, *, w_p: int,
                  w_s: int, a=None, w_a: int = 0, pivot: bool = False,
-                 interpret: bool = True, dtype=None, n_active=None):
+                 interpret: bool, dtype=None, n_active=None):
         D, n = sort_idx.shape
         self.D, self.n = D, n
         self.w_a, self.w_p, self.w_s = w_a, w_p, w_s

@@ -16,6 +16,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ..core.matern import _poly_coeffs
@@ -23,6 +24,9 @@ from ..core.matern import _poly_coeffs
 __all__ = ["kp_gram_pallas"]
 
 DEF_BLOCK = 512
+# int32 block index: under x64 a literal 0 in an index map is int64, which
+# Mosaic refuses
+_I0 = np.int32(0)
 
 
 def _matern(q, omega, r):
@@ -43,22 +47,24 @@ def _kernel(om_ref, a_ref, xp_ref, xc_ref, xn_ref, o_ref, *, q, block, n):
     i0 = pl.program_id(0) * block
     rows = i0 + jax.lax.iota(jnp.int32, block)
     acc = jnp.zeros((block, 2 * q + 1), a.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
     for m in range(-q, q + 1):
-        xm = jax.lax.dynamic_slice_in_dim(xx, block + m, block, axis=0)
+        xm = xx[block + m : 2 * block + m]  # static shift
         row_m = jnp.zeros((block,), a.dtype)
         for t in range(-lo, lo + 1):
-            xt = jax.lax.dynamic_slice_in_dim(xx, block + t, block, axis=0)
+            xt = xx[block + t : 2 * block + t]
             kv = _matern(q, omega, jnp.abs(xm - xt))
             valid = ((rows + t) >= 0) & ((rows + t) < n)
             row_m = row_m + jnp.where(valid, a[:, lo + t] * kv, 0.0)
         valid_m = ((rows + m) >= 0) & ((rows + m) < n)
-        acc = acc.at[:, q + m].set(jnp.where(valid_m, row_m, 0.0))
+        col = jnp.where(valid_m, row_m, 0.0)[:, None]
+        acc = jnp.where(lane == q + m, col, acc)
     o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("q", "block", "interpret"))
 def kp_gram_pallas(q: int, omega, xs: jax.Array, a_band: jax.Array,
-                   block: int = DEF_BLOCK, interpret: bool = True):
+                   block: int = DEF_BLOCK, *, interpret: bool):
     """xs: (n,) sorted; a_band: (n, 2q+3) -> Phi band (n, 2q+1)."""
     n = xs.shape[0]
     wA = 2 * q + 3
@@ -73,13 +79,13 @@ def kp_gram_pallas(q: int, omega, xs: jax.Array, a_band: jax.Array,
         functools.partial(_kernel, q=q, block=block, n=n),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((block, wA), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i + 1, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i + 2, 0)),
+            pl.BlockSpec((1, 1), lambda i: (_I0, _I0)),
+            pl.BlockSpec((block, wA), lambda i: (i, _I0)),
+            pl.BlockSpec((block, 1), lambda i: (i, _I0)),
+            pl.BlockSpec((block, 1), lambda i: (i + 1, _I0)),
+            pl.BlockSpec((block, 1), lambda i: (i + 2, _I0)),
         ],
-        out_specs=pl.BlockSpec((block, 2 * q + 1), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((block, 2 * q + 1), lambda i: (i, _I0)),
         out_shape=jax.ShapeDtypeStruct((npad, 2 * q + 1), a_band.dtype),
         interpret=interpret,
     )(om, a_p, xz, xz, xz)

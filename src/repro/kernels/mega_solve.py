@@ -129,7 +129,7 @@ def _jacobi_solve_kernel(sig_ref, v_ref, x0_ref, phi_ref, saphi_ref,
 def mega_jacobi_solve_pallas(phi, saphi, sort_idx, rank_idx, sigma2, v, x0,
                              *, w_p: int, w_s: int, alpha: float, iters: int,
                              pivot: bool = False, warm: bool = False,
-                             interpret: bool = True):
+                             interpret: bool):
     """Whole damped-Jacobi solve; returns ``(x, k)`` (pre-padded operands)."""
     D, npad, B = v.shape
     dtype = v.dtype
@@ -183,7 +183,7 @@ def _gs_solve_kernel(sig_ref, v_ref, x0_ref, phi_ref, saphi_ref, sort_ref,
 def mega_gauss_seidel_solve_pallas(phi, saphi, sort_idx, rank_idx, sigma2, v,
                                    x0, *, w_p: int, w_s: int, iters: int,
                                    pivot: bool = False,
-                                   interpret: bool = True):
+                                   interpret: bool):
     """Whole Gauss-Seidel solve; returns ``(x, k)`` (pre-padded operands)."""
     D, npad, B = v.shape
     dtype = v.dtype
@@ -268,7 +268,7 @@ def _pcg_solve_kernel(sig_ref, v_ref, x0_ref, a_ref, phi_ref, saphi_ref,
 def mega_pcg_solve_pallas(a, phi, saphi, sort_idx, rank_idx, sigma2, v, x0,
                           *, w_a: int, w_p: int, w_s: int, iters: int,
                           tol: float = 0.0, pivot: bool = False,
-                          warm: bool = False, interpret: bool = True):
+                          warm: bool = False, interpret: bool):
     """Whole PCG solve; returns ``(x, r, iters_used)`` (pre-padded operands).
 
     ``iters_used`` is the realized iteration count (int32 scalar): the

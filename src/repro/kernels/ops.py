@@ -5,11 +5,13 @@ matmul, KP Gram assembly — routes through this module and is served by one
 of two backends:
 
   * ``"jax"``    — the pure-jax ``lax.scan`` reference implementations in
-                   ``repro.core.banded`` (compiled, CPU/GPU/TPU).
-  * ``"pallas"`` — the Pallas TPU kernels in this package, automatically run
-                   in interpret mode off-TPU so the same code path is
-                   testable everywhere.
-  * ``"auto"``   — resolves to ``"pallas"`` on TPU, ``"jax"`` elsewhere.
+                   ``repro.core.banded`` (compiled by XLA, CPU/GPU/TPU).
+  * ``"pallas"`` — the Pallas TPU kernels in this package. Whether they run
+                   compiled or in the interpreter is decided in one place,
+                   ``interpret_kernels()``: interpreted exactly when no TPU
+                   is attached, so the same code path is testable on a CPU,
+                   and never interpreted on a TPU.
+  * ``"auto"``   — resolved by the **backend rule** below.
 
 Selection precedence (first wins):
   1. an explicit ``"jax"``/``"pallas"`` ``backend=`` argument (threaded from
@@ -18,10 +20,26 @@ Selection precedence (first wins):
      ``REPRO_BACKEND`` environment variable (consulted when the argument is
      ``None`` or ``"auto"`` — the config default — so the env var reaches
      every routed op in the GP core),
-  3. platform: ``"pallas"`` on TPU, ``"jax"`` elsewhere.
+  3. the backend rule, a static function of what the code can observe — the
+     platform and the compute dtype:
 
-Backend choice is a trace-time static, so jitted GP entry points specialize
-per backend (``GPConfig`` is a static/meta field throughout).
+       * 64-bit operands resolve to ``"jax"`` on every platform: Mosaic, the
+         Pallas TPU compiler, has no 64-bit types;
+       * otherwise ``"pallas"`` on a TPU and ``"jax"`` elsewhere.
+
+     An explicit ``"pallas"`` with 64-bit operands on a TPU raises instead
+     of falling back to the interpreter.
+
+``fit()`` applies the rule once, with the data's dtype, and bakes the result
+into the GP config, so the choice is a trace-time static and jitted GP entry
+points specialize per backend (``GPConfig`` is a static/meta field).
+
+On a TPU the ``"pallas"`` backend additionally never routes to a kernel named
+in ``TPU_UNLOWERED`` — kernels the TPU compiler still refuses (pinned by the
+compile rehearsals in ``tests/test_tpu_lowering.py``). Each such op runs its
+jax-scan form instead, and the fused solve modes that need one resolve to
+``"off"`` (or raise when requested explicitly). This is a named static
+exclusion, not a caught compile error.
 
 The pallas solve/logdet path additionally selects between two kernel
 algorithms (``REPRO_SOLVE_ALG`` env / ``set_solve_alg`` / the per-op
@@ -149,8 +167,33 @@ _gband = _env_mode(ENV_GBAND, GBAND_MODES)
 _health = _env_mode(ENV_HEALTH, HEALTH_MODES)
 
 
+# Pallas kernels that do not lower for the TPU yet (see ROADMAP.md queue 1):
+#   * fused_sweep / mega_solve — in-kernel sort/rank permutation gathers;
+#   * rgf — per-step dynamic block indexing and a pivoted scan-LU per block;
+#   * block_cr — (nb, w, w) block layout with in-kernel scatters/reshapes;
+#   * banded_lu — 1-D row updates by scatter in the row recurrence.
+# On a TPU no resolver routes to these; every other kernel compiles (f32).
+TPU_UNLOWERED = frozenset(
+    {"fused_sweep", "mega_solve", "rgf", "block_cr", "banded_lu"})
+
+
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_kernels() -> bool:
+    """Whether Pallas kernels run in the interpreter: exactly off-TPU.
+
+    The one place that decides; every kernel wrapper takes ``interpret``
+    without a default, and on a TPU this never returns True.
+    """
+    return not on_tpu()
+
+
+def kernel_lowers(name: str) -> bool:
+    """False when ``name`` is a kernel the attached TPU cannot compile yet
+    (``TPU_UNLOWERED``); always True off-TPU, where kernels interpret."""
+    return not (on_tpu() and name in TPU_UNLOWERED)
 
 
 def get_backend() -> str:
@@ -177,12 +220,16 @@ def use_backend(name: str):
         set_backend(prev)
 
 
-def resolve_backend(backend: str | None = None) -> str:
+def resolve_backend(backend: str | None = None, dtype=None) -> str:
     """Resolve an op-level override (or the global default) to jax|pallas.
 
     An explicit "jax"/"pallas" wins; "auto" (the GPConfig/SolveConfig
     default) and None defer to the process default (set_backend /
-    REPRO_BACKEND); an "auto" process default resolves by platform.
+    REPRO_BACKEND); an "auto" process default resolves by the backend rule
+    (module docstring): 64-bit ``dtype`` -> "jax" on every platform, else
+    "pallas" on TPU and "jax" elsewhere. ``dtype`` None means "not known
+    here" (the per-op calls on an already-baked config). A "pallas" that
+    would compile 64-bit operands on a TPU raises.
     """
     b = backend if backend is not None else _backend
     if b not in BACKENDS:
@@ -195,8 +242,14 @@ def resolve_backend(backend: str | None = None) -> str:
             raise ValueError(
                 f"unknown backend {b!r} (from {ENV_VAR} or set_backend); "
                 f"expected one of {BACKENDS}")
+    wide = dtype is not None and jnp.dtype(dtype).itemsize == 8
     if b == "auto":
-        return "pallas" if on_tpu() else "jax"
+        return "pallas" if on_tpu() and not wide else "jax"
+    if b == "pallas" and wide and on_tpu():
+        raise ValueError(
+            f"backend='pallas' cannot run {jnp.dtype(dtype).name} operands "
+            "on a TPU: Mosaic has no 64-bit types. Use backend='auto' or "
+            "'jax' (XLA), or compute in float32")
     return b
 
 
@@ -320,7 +373,12 @@ def resolve_fused(fused: str | None, backend: str | None, *, widths,
         return "off"
     be = resolve_backend(backend)
     symmetric = all(lo == hi for lo, hi in widths)
+    kernel = "mega_solve" if f == "whole" else "fused_sweep"
     if f in ("on", "whole"):
+        if not kernel_lowers(kernel):
+            raise ValueError(
+                f"fused={f!r} needs the {kernel} kernel, which does not "
+                "lower for the TPU yet (kernels.ops.TPU_UNLOWERED)")
         if be != "pallas":
             raise ValueError(
                 f"fused={f!r} requires the pallas backend (got "
@@ -343,11 +401,11 @@ def resolve_fused(fused: str | None, backend: str | None, *, widths,
     if be != "pallas" or not symmetric or not cr_ok or precond == "kmg":
         return "off"
     ws = [lo for lo, _ in widths]
-    if mega_solve.mega_vmem_bytes(
+    if kernel_lowers("mega_solve") and mega_solve.mega_vmem_bytes(
             n, D, B, ws, itemsize, method=method) <= fused_sweep.VMEM_CAP_BYTES:
         return "whole"
-    if fused_vmem_bytes(n, D, B, ws, itemsize,
-                        method=method) <= fused_sweep.VMEM_CAP_BYTES:
+    if kernel_lowers("fused_sweep") and fused_vmem_bytes(
+            n, D, B, ws, itemsize, method=method) <= fused_sweep.VMEM_CAP_BYTES:
         return "iter"
     return "off"
 
@@ -516,10 +574,6 @@ def resolve_health(health: str | None = None) -> str:
     return h
 
 
-def _interpret() -> bool:
-    return not on_tpu()
-
-
 def _core():
     # deferred: repro.core.banded lazily imports this module in its public
     # dispatchers, so neither side may import the other at module load
@@ -559,7 +613,7 @@ def banded_matvec(band, x, lo: int, hi: int, block: int = 512,
     bd = _core()
     n = band.shape[-2]
     mat_form = x.ndim >= 2 and x.shape[-2] == n and x.ndim == band.ndim
-    if resolve_backend(backend) == "jax":
+    if resolve_backend(backend, jnp.result_type(band, x)) == "jax":
         if n_active is not None:
             band = canonical_band(band, lo, hi, n_active)
             x = mask_rows(x, n_active, axis=-2 if mat_form else -1)
@@ -567,7 +621,7 @@ def banded_matvec(band, x, lo: int, hi: int, block: int = 512,
     xb = x if mat_form else x[..., None]
     batch, (bf, xf) = _flatten_batch((band, xb), (2, 2))
     out = banded_matvec_pallas(bf, xf, lo, hi, block=block,
-                               interpret=_interpret(), n_active=n_active)
+                               interpret=interpret_kernels(), n_active=n_active)
     out = out.reshape(batch + out.shape[-2:])
     return out if mat_form else out[..., 0]
 
@@ -588,24 +642,21 @@ def banded_solve(band, rhs, lo: int, hi: int, pivot: bool = False,
     bd = _core()
     n = band.shape[-2]
     vec_in = rhs.shape[-1] == n and rhs.ndim == band.ndim - 1
-    if resolve_backend(backend) == "jax":
+    be = resolve_backend(backend, jnp.result_type(band, rhs))
+    use_cr = be == "pallas" and resolve_solve_alg(alg, lo, hi) == "cr"
+    if (be == "jax" or (pivot and not use_cr)
+            or not kernel_lowers("block_cr" if use_cr else "banded_lu")):
         if n_active is not None:
             band = canonical_band(band, lo, hi, n_active)
             rhs = mask_rows(rhs, n_active, axis=-1 if vec_in else -2)
         return bd._solve_scan(bd.Banded(band, lo, hi), rhs, pivot=pivot)
-    use_cr = resolve_solve_alg(alg, lo, hi) == "cr"
-    if pivot and not use_cr:
-        if n_active is not None:
-            band = canonical_band(band, lo, hi, n_active)
-            rhs = mask_rows(rhs, n_active, axis=-1 if vec_in else -2)
-        return bd._solve_scan(bd.Banded(band, lo, hi), rhs, pivot=True)
     rb = rhs[..., None] if vec_in else rhs
     batch, (bf, rf) = _flatten_batch((band, rb), (2, 2))
     if use_cr:
         x = block_cr_solve_pallas(bf, rf, lo, pivot=pivot,
-                                  interpret=_interpret(), n_active=n_active)
+                                  interpret=interpret_kernels(), n_active=n_active)
     else:
-        x = banded_solve_pallas(bf, rf, lo, hi, interpret=_interpret(),
+        x = banded_solve_pallas(bf, rf, lo, hi, interpret=interpret_kernels(),
                                 n_active=n_active)
     out = x.reshape(batch + x.shape[-2:])
     return out[..., 0] if vec_in else out
@@ -624,20 +675,19 @@ def banded_logdet(band, lo: int, hi: int, pivot: bool = False,
     the active log-determinant.
     """
     bd = _core()
-    if resolve_backend(backend) == "jax":
-        band = canonical_band(band, lo, hi, n_active)
-        return bd._logdet_scan(bd.Banded(band, lo, hi))
-    use_cr = resolve_solve_alg(alg, lo, hi) == "cr"
-    if pivot and not use_cr:
+    be = resolve_backend(backend, band.dtype)
+    use_cr = be == "pallas" and resolve_solve_alg(alg, lo, hi) == "cr"
+    if (be == "jax" or (pivot and not use_cr)
+            or not kernel_lowers("block_cr" if use_cr else "banded_lu")):
         band = canonical_band(band, lo, hi, n_active)
         return bd._logdet_scan(bd.Banded(band, lo, hi))
     batch, (bf,) = _flatten_batch((band,), (2,))
     if use_cr:
         ld = block_cr_logdet_pallas(bf, lo, pivot=pivot,
-                                    interpret=_interpret(),
+                                    interpret=interpret_kernels(),
                                     n_active=n_active)
     else:
-        ld = banded_logdet_pallas(bf, lo, hi, interpret=_interpret(),
+        ld = banded_logdet_pallas(bf, lo, hi, interpret=interpret_kernels(),
                                   n_active=n_active)
     return ld.reshape(batch)
 
@@ -651,7 +701,7 @@ def band_band_matmul(a_band, b_band, a_lo: int, a_hi: int, b_lo: int,
     result's tail is again a canonical identity tail (at the wider band).
     """
     bd = _core()
-    if resolve_backend(backend) == "jax":
+    if resolve_backend(backend, jnp.result_type(a_band, b_band)) == "jax":
         a_band = canonical_band(a_band, a_lo, a_hi, n_active)
         b_band = canonical_band(b_band, b_lo, b_hi, n_active)
         return bd._band_band_matmul_scan(
@@ -659,7 +709,7 @@ def band_band_matmul(a_band, b_band, a_lo: int, a_hi: int, b_lo: int,
         ).data
     batch, (af, bf) = _flatten_batch((a_band, b_band), (2, 2))
     out = band_matmul_pallas(af, bf, a_lo, a_hi, b_lo, b_hi, block=block,
-                             interpret=_interpret(), n_active=n_active)
+                             interpret=interpret_kernels(), n_active=n_active)
     out = out.reshape(batch + out.shape[-2:])
     n = a_band.shape[-2]
     return out * bd._band_mask(n, a_lo + b_lo, a_hi + b_hi)
@@ -668,9 +718,9 @@ def band_band_matmul(a_band, b_band, a_lo: int, a_hi: int, b_lo: int,
 def kp_gram(q: int, omega, xs, a_band, block: int = 512,
             backend: str | None = None):
     """Fused Phi = A K band assembly (Algorithm 2)."""
-    if resolve_backend(backend) == "jax":
+    if resolve_backend(backend, jnp.result_type(xs, a_band)) == "jax":
         from .ref import kp_gram_ref
 
         return kp_gram_ref(q, omega, xs, a_band)
     return kp_gram_pallas(q, omega, xs, a_band, block=block,
-                          interpret=_interpret())
+                          interpret=interpret_kernels())

@@ -87,7 +87,7 @@ def _rgf_kernel(dg_ref, u_ref, l_ref, gd_ref, gu_ref, gl_ref, f_scr, w_scr,
 
 
 @functools.partial(jax.jit, static_argnames=("T", "w", "interpret"))
-def rgf_blocks_pallas(Dg, U, L, *, T: int, w: int, interpret: bool = True):
+def rgf_blocks_pallas(Dg, U, L, *, T: int, w: int, interpret: bool):
     """(G, T, w, w) block-tridiagonal stacks -> (Gd, Gu, Gl) of the inverse.
 
     ``Gu[j] = G_{j, j+1}``, ``Gl[j] = G_{j+1, j}`` (last entries zero), as
@@ -111,7 +111,7 @@ def rgf_blocks_pallas(Dg, U, L, *, T: int, w: int, interpret: bool = True):
 
 
 def rgf_inverse_band(data, lo: int, hi: int, hw: int, *,
-                     interpret: bool = True):
+                     interpret: bool):
     """Band (half-bw ``hw``) of H^{-1}; ``data`` (..., n, lo+hi+1) band rows.
 
     The block partition and band extraction are the scan path's own

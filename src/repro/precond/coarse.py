@@ -54,6 +54,7 @@ from ..core import matern as mk
 from ..core.backfitting import DimOps
 from ..core.banded import Banded, add, scale
 from ..core.kernel_packets import gram_band_rows, kp_coefficient_rows
+from ..core.ordering import argsort_rows, inverse_perm, separate_ties
 from ..masking import mask_rows, tree_sum
 
 __all__ = ["CoarseLevel", "build_hierarchy", "coarse_capacity",
@@ -132,12 +133,10 @@ def _coarse_sorted(Xc_t: jax.Array, nc_active):
     span = hi - lo + 1.0
     fill = hi + span * (j[None, :] - na + 1.0)
     xc = jnp.where(act[None, :], Xc_t, fill)
-    sort_idx = jnp.argsort(xc, axis=1).astype(jnp.int32)
+    sort_idx = argsort_rows(xc)
     xs_c = jnp.take_along_axis(xc, sort_idx, axis=1)
-    rank_idx = jnp.argsort(sort_idx, axis=1).astype(jnp.int32)
-    gaps = jnp.diff(xs_c, axis=1)
-    bump = jnp.cumsum(jnp.where(gaps <= 0, span * _TIE_EPS, 0.0), axis=1)
-    xs_c = xs_c.at[:, 1:].add(bump)
+    rank_idx = inverse_perm(sort_idx)
+    xs_c = separate_ties(xs_c, span * _TIE_EPS)
     return xs_c, sort_idx, rank_idx
 
 

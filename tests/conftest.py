@@ -1,20 +1,13 @@
-import os
-import tempfile
-
 import jax
+
+from repro import compile_cache
 
 # GP-core numerics are validated against dense float64 oracles; model smoke
 # tests use explicit dtypes so the global x64 flag does not affect them.
 jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: the suite is compile-bound on CPU, so
-# repeat runs (local dev, CI retries) skip most of the ~compile cost. Guarded:
-# harmless to skip on jax versions without the flags.
-try:
-    _cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "jax_compilation_cache"))
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-except Exception:  # pragma: no cover - older/newer jax flag drift
-    pass
+# repeat runs (local dev, CI retries) skip most of the compile cost. The
+# directory is JAX_COMPILATION_CACHE_DIR when set, else <repo>/.jax_cache.
+compile_cache.configure()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)

@@ -251,6 +251,101 @@ def test_backend_selection_rules():
         ops.set_backend(prev)
 
 
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+def test_backend_rule_64bit_resolves_to_jax(monkeypatch, tpu):
+    """"auto": 64-bit operands -> jax on every platform (Mosaic has no
+    64-bit types); 32-bit -> pallas exactly on a TPU. fit() bakes the rule
+    from the data's dtype."""
+    from repro.core import GPConfig, fit
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    assert ops.resolve_backend("auto", jnp.float64) == "jax"
+    assert ops.resolve_backend("auto", jnp.float32) == (
+        "pallas" if tpu else "jax")
+    assert ops.resolve_backend("auto") == ("pallas" if tpu else "jax")
+    rng = np.random.default_rng(3)
+    X = jnp.asarray(rng.random((12, 2)))
+    gp = fit(GPConfig(q=0, solver_iters=5), X, jnp.asarray(rng.random(12)),
+             jnp.ones(2), 0.5)
+    assert gp.config.backend == "jax"
+
+
+def test_explicit_pallas_64bit_on_tpu_raises(monkeypatch):
+    """An explicit pallas request with 64-bit operands on a TPU is an error,
+    never a silent interpreter or backend fallback; off-TPU it stays legal
+    (the interpreter runs f64, which the parity suites rely on)."""
+    from repro.core import GPConfig, fit
+
+    assert ops.resolve_backend("pallas", jnp.float64) == "pallas"
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="64-bit"):
+        ops.resolve_backend("pallas", jnp.float64)
+    assert ops.resolve_backend("pallas", jnp.float32) == "pallas"
+    rng = np.random.default_rng(4)
+    with pytest.raises(ValueError, match="64-bit"):
+        fit(GPConfig(q=0, solver_iters=5, backend="pallas"),
+            jnp.asarray(rng.random((12, 2))), jnp.asarray(rng.random(12)),
+            jnp.ones(2), 0.5)
+    with pytest.raises(ValueError, match="64-bit"):
+        ops.banded_matvec(jnp.ones((8, 3)), jnp.ones(8), 1, 1,
+                          backend="pallas")
+
+
+def test_no_kernel_wrapper_defaults_to_interpret(monkeypatch):
+    """Every Pallas wrapper takes ``interpret`` with no default; the one
+    helper that decides it never returns True on a TPU."""
+    import inspect
+
+    from repro.kernels import (band_matmul, banded_lu, banded_matvec,
+                               block_cr, fused_sweep, kp_gram, mega_solve,
+                               rgf)
+
+    wrappers = [
+        band_matmul.band_matmul_pallas, banded_lu.banded_lu_pallas,
+        banded_lu.banded_solve_pallas, banded_lu.banded_logdet_pallas,
+        banded_matvec.banded_matvec_pallas, block_cr.block_cr_pallas,
+        block_cr.block_cr_solve_pallas, block_cr.block_cr_logdet_pallas,
+        kp_gram.kp_gram_pallas, rgf.rgf_blocks_pallas, rgf.rgf_inverse_band,
+        fused_sweep.fused_jacobi_iter_pallas,
+        fused_sweep.fused_gauss_seidel_iter_pallas,
+        fused_sweep.fused_pcg_iter_pallas, fused_sweep.FusedSweep,
+        mega_solve.mega_jacobi_solve_pallas,
+        mega_solve.mega_gauss_seidel_solve_pallas,
+        mega_solve.mega_pcg_solve_pallas,
+    ]
+    for fn in wrappers:
+        param = inspect.signature(fn).parameters["interpret"]
+        assert param.default is inspect.Parameter.empty, fn
+    assert ops.interpret_kernels() is True  # this suite runs off-TPU
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops.interpret_kernels() is False
+
+
+def test_unlowered_kernels_are_excluded_on_tpu(monkeypatch):
+    """On a TPU no resolver routes to a TPU_UNLOWERED kernel: "auto" fusion
+    resolves off, an explicit fused mode raises, and the per-op dispatch
+    runs the jax scan in place of the excluded solve kernels."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    widths = ((1, 1), (0, 0), (1, 1))
+    assert not ops.kernel_lowers("mega_solve")
+    assert ops.kernel_lowers("banded_matvec")
+    assert ops.resolve_fused("auto", "pallas", widths=widths, n=64, D=2,
+                             itemsize=4, precond="none") == "off"
+    for mode in ("whole", "on"):
+        with pytest.raises(ValueError, match="TPU_UNLOWERED"):
+            ops.resolve_fused(mode, "pallas", widths=widths, n=64, D=2,
+                              itemsize=4, precond="none")
+    rng = np.random.default_rng(5)
+    band = _rand_band(rng, 16, 1, 1, jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((16, 2)), jnp.float32)
+    jaxpr = str(jax.make_jaxpr(lambda b, r: ops.banded_solve(
+        b, r, 1, 1, backend="pallas"))(band, rhs))
+    assert "pallas_call" not in jaxpr
+    jaxpr = str(jax.make_jaxpr(lambda b, r: ops.banded_matvec(
+        b, r, 1, 1, backend="pallas"))(band, rhs))
+    assert "pallas_call" in jaxpr
+
+
 def test_invalid_env_default_raises_on_auto(monkeypatch):
     """A typo'd REPRO_BACKEND must raise, not silently pick a backend, even
     through the config-level "auto" deferral path."""
@@ -310,7 +405,7 @@ def test_fit_resolves_backend_into_config():
         gp = fit(GPConfig(q=0, solver_iters=5), X, Y, om, 0.5)
     assert gp.config.backend == "pallas"
     gp2 = fit(GPConfig(q=0, solver_iters=5), X, Y, om, 0.5)
-    assert gp2.config.backend == ("pallas" if ops.on_tpu() else "jax")
+    assert gp2.config.backend == "jax"  # float64 data: XLA on every platform
 
 
 def test_gp_end_to_end_backend_parity():

@@ -419,18 +419,20 @@ def test_grid_batched_kernels_match_single_calls():
         (rng.standard_normal((G, n, w)) + 5.0 * (m == 0)) * mask)
     x = jnp.asarray(rng.standard_normal((G, n, 2)))
 
-    ymv = banded_matvec_pallas(band, x, lo, hi, block=16)
-    ymm = band_matmul_pallas(band, band, lo, hi, lo, hi, block=16)
-    ylu, ld = banded_lu_pallas(band, x, lo, hi)
+    ymv = banded_matvec_pallas(band, x, lo, hi, block=16, interpret=True)
+    ymm = band_matmul_pallas(band, band, lo, hi, lo, hi, block=16,
+                             interpret=True)
+    ylu, ld = banded_lu_pallas(band, x, lo, hi, interpret=True)
     assert ylu.shape == x.shape and ld.shape == (G,)
     for g in range(G):
         np.testing.assert_array_equal(
             np.asarray(ymv[g]),
-            np.asarray(banded_matvec_pallas(band[g], x[g], lo, hi, block=16)))
+            np.asarray(banded_matvec_pallas(band[g], x[g], lo, hi, block=16,
+                                            interpret=True)))
         np.testing.assert_array_equal(
             np.asarray(ymm[g]),
             np.asarray(band_matmul_pallas(band[g], band[g], lo, hi, lo, hi,
-                                          block=16)))
-        x1, ld1 = banded_lu_pallas(band[g], x[g], lo, hi)
+                                          block=16, interpret=True)))
+        x1, ld1 = banded_lu_pallas(band[g], x[g], lo, hi, interpret=True)
         np.testing.assert_array_equal(np.asarray(ylu[g]), np.asarray(x1))
         assert float(ld[g]) == float(ld1)

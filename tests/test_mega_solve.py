@@ -60,7 +60,7 @@ def _cfg(method, fused, **kw):
 
 
 def _subjaxprs(params):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
