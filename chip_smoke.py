@@ -2,7 +2,7 @@
 """Chip smoke test: the main path once, on one TPU chip, through the entry
 points a user calls.
 
-    python chip_smoke.py [--n 16000] [--queries 256] [--subset 2000]
+    python chip_smoke.py
 
 Phases (each program is compiled ahead of time to report its compile
 seconds and device memory apart from its run seconds; the entry point then
@@ -20,7 +20,10 @@ reuses that executable):
                   Cholesky GP of ``repro.core.exact`` (mean, variance, MLL),
                   computed on the host CPU.
   4. serving    — a ``GPServeEngine`` at capacity 16384: ticks of mean, var
-                  and acq queries, 8 inserts and 1 evict behind the fence.
+                  and acq queries, 8 inserts and 1 evict behind the fence,
+                  then 2 more and 1 more; the last answers are checked
+                  against a fresh fit of the engine's points at the same
+                  capacity.
   5. kernels    — every Pallas kernel that lowers for the TPU, compiled
                   (never interpreted), in f32 against its jax-scan form.
 
@@ -31,7 +34,6 @@ device. Run it on a host with a TPU; anywhere else it fails.
 """
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import sys
@@ -41,6 +43,15 @@ import traceback
 import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
+
+# the Fig. 5 grid size, the query count, the dense-oracle subset and the
+# engine's capacity tier are fixed: the smoke proves this one size
+N = 16000
+D = 10
+QUERIES = 256
+SUBSET = 2000
+CAPACITY = 16384
+SEED = 0
 
 # tolerances against the dense oracle on the subset: the solves stop after
 # 40 PCG iterations (mean, var), and the MLL log-determinant is a 16-probe
@@ -53,14 +64,19 @@ KERNEL_RTOL = 1e-5
 
 def smoke_config():
     """The Fig. 5 configuration: the paper's block-preconditioned PCG, 40
-    iterations (at n=16000 it exits at ~1e-11 relative residual). The KMG
-    V-cycle that ``precond="auto"`` adds at n >= 4096 is left out: in
-    float64 its program is the bulk of the XLA compile on the TPU (ROADMAP
-    queue 1). The data is densely oversampled (omega times the point gap is
-    ~1e-3 at n=16000), outside the windowed Gband patch's truncation
-    contract, so mutations keep the variance band exact with the full RGF
-    sweep, as the streaming README prescribes for such data; the drift
-    sentinel would otherwise resync after every fence."""
+    iterations (at n=16000 it exits at ~1e-11 relative residual). This is
+    not ``GPConfig()``'s default at this n, in two ways:
+
+    * ``precond="none"``: the KMG V-cycle that ``"auto"`` adds at
+      n >= 4096 is left out, because in float64 its program is the bulk of
+      the XLA compile on the TPU (ROADMAP queue 1);
+    * ``gband="full"``: the data is densely oversampled (omega times the
+      point gap is ~1e-3 at n=16000), outside the windowed Gband patch's
+      truncation contract, so mutations keep the variance band exact with
+      the full RGF sweep, as the streaming README prescribes for such data.
+      The default's drift sentinel would resync after every fence instead.
+
+    Neither default path (KMG, windowed Gband) runs here."""
     from repro.core import GPConfig
 
     return GPConfig(q=0, solver="pcg", solver_iters=40, precond="none",
@@ -147,7 +163,7 @@ def posterior(tag, X, Y, Xq, omega, sigma, key, hbm_bytes=None):
     return gp, mu, var, ll
 
 
-def main_path(args, data, hbm_bytes):
+def main_path(data, hbm_bytes):
     X, Y, Xq, omega, sigma, key = data
     gp, mu, var, ll = posterior("", X, Y, Xq, omega, sigma, key, hbm_bytes)
     log(f"main: n={X.shape[0]} mll={float(ll):.6f} "
@@ -167,13 +183,13 @@ def host_device():
         return jax.devices()[0]
 
 
-def reference(args, data):
+def reference(data):
     import jax
 
     from repro.core import exact
 
     X, Y, Xq, omega, sigma, key = data
-    Xs, Ys = X[: args.subset], Y[: args.subset]
+    Xs, Ys = X[:SUBSET], Y[:SUBSET]
     _, mu, var, ll = posterior("[subset]", Xs, Ys, Xq, omega, sigma, key)
     host = host_device()
     log(f"reference: dense oracle on {host.platform}")
@@ -186,7 +202,7 @@ def reference(args, data):
                    exact.log_marginal_likelihood, 0, om_h, sigma, Xs_h, Ys_h)
     errs = {"mean": rel_err(mu, mu_x), "var": rel_err(var, var_x),
             "mll": abs(float(ll) - float(ll_x)) / abs(float(ll_x))}
-    log(f"reference: n={args.subset} rel_err mean={errs['mean']:.3e} "
+    log(f"reference: n={SUBSET} rel_err mean={errs['mean']:.3e} "
         f"(tol {MEAN_RTOL:g}) var={errs['var']:.3e} (tol {VAR_RTOL:g}) "
         f"mll={errs['mll']:.3e} (tol {MLL_RTOL:g}) "
         f"mll_sparse={float(ll):.6f} mll_exact={float(ll_x):.6f}")
@@ -195,16 +211,17 @@ def reference(args, data):
     check(errs["mll"] <= MLL_RTOL, f"mll rel err {errs['mll']:.3e}")
 
 
-def serving(args, gp, mu, var, data, bounds, f):
+def serving(gp, mu, var, data, bounds, f):
+    from repro.core import fit, posterior_mean, posterior_var
     from repro.streaming import GPServeEngine
 
     X, Y, Xq, omega, sigma, key = data
     Xq_np = np.asarray(Xq)
     t0 = time.perf_counter()
-    eng = GPServeEngine(gp, bounds, batch_slots=8, capacity=args.capacity)
+    eng = GPServeEngine(gp, bounds, batch_slots=8, capacity=CAPACITY)
     log(f"time engine_init[re-home to capacity, incl. compile]: "
         f"{time.perf_counter() - t0:.3f}s")
-    check(eng.capacity == args.capacity, f"engine capacity {eng.capacity}")
+    check(eng.capacity == CAPACITY, f"engine capacity {eng.capacity}")
 
     def serve(label, kinds, xs):
         qs = [eng.submit(x, kind=k) for k, x in zip(kinds, xs)]
@@ -232,7 +249,7 @@ def serving(args, gp, mu, var, data, bounds, f):
           "engine answers disagree with the direct posterior")
     serve("warm", kinds, Xq_np[n_q: 2 * n_q])
 
-    rng = np.random.default_rng(args.seed + 1)
+    rng = np.random.default_rng(SEED + 1)
     x_new = rng.uniform(bounds[:, 0], bounds[:, 1], size=(8, X.shape[1]))
     y_new = f(x_new) + rng.standard_normal(8)
     v0 = eng.version
@@ -261,8 +278,29 @@ def serving(args, gp, mu, var, data, bounds, f):
           and stats["resyncs"] == 0,
           f"health events on the serve path: {stats['events']}")
 
+    # the mutated posterior against a fresh fit of the engine's points (the
+    # active prefix, oldest evicted) at the same capacity, to the oracle
+    # tolerances: each insert and evict re-solves warm for 10 PCG iterations
+    na = eng.num_points
+    fresh = run("fit[engine points, incl. compile]", fit, smoke_config(),
+                eng.gp.X[:na], eng.gp.Y[:na], omega, sigma, capacity=CAPACITY)
+    check(verdict_ok(fresh.health.verdict),
+          f"fresh fit verdict {int(fresh.health.verdict)}")
+    xs = np.stack([q.x for q in qs])
+    f_mu = run("posterior_mean[fresh, incl. compile]", posterior_mean, fresh,
+               xs)
+    f_var = run("posterior_var[fresh, incl. compile]", posterior_var, fresh,
+                xs)
+    e_mu = rel_err([q.result["mean"] for q in qs], f_mu)
+    e_var = rel_err([q.result["var"] for q in qs], f_var)
+    log(f"serving: after mutations, engine vs fresh fit of its {na} points "
+        f"rel_err mean={e_mu:.3e} (tol {MEAN_RTOL:g}) "
+        f"var={e_var:.3e} (tol {VAR_RTOL:g})")
+    check(e_mu <= MEAN_RTOL, f"post-mutation mean rel err {e_mu:.3e}")
+    check(e_var <= VAR_RTOL, f"post-mutation var rel err {e_var:.3e}")
 
-def kernels(args):
+
+def kernels():
     """Each TPU-lowering kernel, compiled, f32, against its jax-scan form."""
     import jax.numpy as jnp
 
@@ -276,7 +314,7 @@ def kernels(args):
 
     check(not ops.interpret_kernels(), "kernels would run interpreted")
     G, n = 10, 16384
-    rng = np.random.default_rng(args.seed)
+    rng = np.random.default_rng(SEED)
     f32 = jnp.float32
 
     def one(name, fn, want, args, keep=None):
@@ -306,16 +344,6 @@ def kernels(args):
 
 
 def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--n", type=int, default=16000,
-                    help="training points of the main path (Fig. 5 grid)")
-    ap.add_argument("--queries", type=int, default=256)
-    ap.add_argument("--subset", type=int, default=2000,
-                    help="points of the dense-oracle comparison")
-    ap.add_argument("--capacity", type=int, default=16384)
-    ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -335,10 +363,9 @@ def main() -> int:
     from repro.data import sample_test_function
     from repro.kernels import ops
 
-    D = 10
-    cfg = resolve_config(smoke_config(), args.n, jnp.float64)
+    cfg = resolve_config(smoke_config(), N, jnp.float64)
     fused = ops.resolve_fused(cfg.fused, cfg.backend,
-                              widths=((1, 1), (0, 0), (1, 1)), n=args.n, D=D,
+                              widths=((1, 1), (0, 0), (1, 1)), n=N, D=D,
                               itemsize=8, method=cfg.solver,
                               precond=cfg.precond)
     hbm = (dev.memory_stats() or {}).get("bytes_limit")
@@ -349,14 +376,13 @@ def main() -> int:
         f"gband={cfg.gband} interpret_kernels={ops.interpret_kernels()} "
         f"cache={cache}")
 
-    X, Y, f, bounds = sample_test_function("schwefel", args.n, D,
-                                           seed=args.seed)
+    X, Y, f, bounds = sample_test_function("schwefel", N, D, seed=SEED)
     span = bounds[:, 1] - bounds[:, 0]
-    Xq = np.random.default_rng(100 + args.seed).uniform(
-        bounds[:, 0], bounds[:, 1], size=(args.queries, D))
+    Xq = np.random.default_rng(100 + SEED).uniform(
+        bounds[:, 0], bounds[:, 1], size=(QUERIES, D))
     data = (jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Xq),
             jnp.asarray(8.0 / span), 1.0,
-            jnp.asarray([0, args.seed], jnp.uint32))
+            jnp.asarray([0, SEED], jnp.uint32))
 
     failed = []
     t_all = time.perf_counter()
@@ -373,13 +399,13 @@ def main() -> int:
             failed.append(name)
             return None
 
-    main_out = phase("main", main_path, args, data, hbm)
-    phase("reference", reference, args, data)
+    main_out = phase("main", main_path, data, hbm)
+    phase("reference", reference, data)
     if main_out is not None:
-        phase("serving", serving, args, *main_out, data, bounds, f)
+        phase("serving", serving, *main_out, data, bounds, f)
     else:
         failed.append("serving")
-    phase("kernels", kernels, args)
+    phase("kernels", kernels)
     stats = dev.memory_stats() or {}
     log(f"device peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
         f"bytes_limit={stats.get('bytes_limit')} "

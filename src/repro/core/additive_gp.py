@@ -63,8 +63,9 @@ class GPConfig:
     solver: str = "pcg"  # backfitting method for Mhat^{-1}
     solver_iters: int = 50
     pivot: bool = False
-    # banded-algebra backend: "auto" (64-bit data -> jax; else pallas on TPU,
-    # jax elsewhere) | "jax" | "pallas"; threaded through every
+    # banded-algebra backend: "auto" (64-bit data -> jax; else pallas on a
+    # TPU where every kernel lowers, jax otherwise) | "jax" | "pallas";
+    # threaded through every
     # matvec/solve/logdet via kernels.ops
     backend: str = "auto"
     # pallas solve/logdet kernel: "auto" (block CR when lo == hi, else LU) |
@@ -243,7 +244,8 @@ def fit(config: GPConfig, X: jax.Array, Y: jax.Array, omega: jax.Array, sigma,
 def resolve_config(config: GPConfig, n: int, dtype) -> GPConfig:
     """Bake every "auto" mode of ``config`` for an ``n``-point fit computed
     in ``dtype``. The backend follows the rule in ``kernels.ops`` (64-bit
-    -> "jax"; else "pallas" on TPU); shared by ``fit`` and ``fleet_fit``."""
+    -> "jax"; else "pallas" on a TPU where every kernel lowers); shared by
+    ``fit`` and ``fleet_fit``."""
     from ..kernels import ops as _kops
 
     return dataclasses.replace(

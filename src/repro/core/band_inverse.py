@@ -196,19 +196,19 @@ def inverse_band(H: Banded, hw: int, backend: str | None = None) -> Banded:
 
     On the pallas backend the recurrences run on-chip
     (``kernels/rgf.py`` — one ``pallas_call`` for the whole batch, bit-
-    identical to the scans here) wherever that kernel lowers
-    (``kernels.ops.kernel_lowers``); ``backend`` resolves like every
-    dispatched op (``kernels.ops.resolve_backend``).
+    identical to the scans here); on a TPU that cannot compile it yet this
+    raises (``kernels.ops.require_lowered``). ``backend`` resolves like
+    every dispatched op (``kernels.ops.resolve_backend``).
     """
     n_active = H.n_active
     if n_active is not None:
         H = H.canonical()
     from ..kernels import ops as _kops
 
-    if (_kops.resolve_backend(backend, H.data.dtype) == "pallas"
-            and _kops.kernel_lowers("rgf")):
+    if _kops.resolve_backend(backend, H.data.dtype) == "pallas":
         from ..kernels.rgf import rgf_inverse_band
 
+        _kops.require_lowered("rgf")
         out = rgf_inverse_band(H.data, H.lo, H.hi, hw,
                                interpret=_kops.interpret_kernels())
         return Banded(out, hw, hw, n_active)

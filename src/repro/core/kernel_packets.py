@@ -45,7 +45,7 @@ def _kp_row_inputs(n, q: int, rows: jax.Array, clip_n: int | None = None):
     """Per-row window gather indices + Algorithm-2 category for ``rows``.
 
     Returns (window indices (r, 2q+3), validity, primary sign, aux sign,
-    number of valid auxiliary equations) — everything ``_kp_build_row`` needs,
+    number of valid auxiliary equations) — everything ``_kp_build_rows`` needs,
     for an arbitrary subset of row indices (streaming updates rebuild only the
     O(q) window around an inserted point). ``n`` may be a *traced* active
     length (capacity padding) — it only enters comparisons; ``clip_n`` is the

@@ -22,17 +22,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from ..masking import canonical_band
+from .banded_matvec import I0
 
 __all__ = ["band_matmul_pallas"]
 
 DEF_BLOCK = 512
-# int32 block index: under x64 a literal 0 in an index map is int64, which
-# Mosaic refuses
-_I0 = np.int32(0)
 
 
 def _kernel(a_ref, bp_ref, bc_ref, bn_ref, o_ref, *, a_lo, a_hi, b_lo, b_hi,
@@ -91,12 +88,12 @@ def band_matmul_pallas(a_band: jax.Array, b_band: jax.Array,
                           block=block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block, wa), lambda g, i: (g, i, _I0)),
-            pl.BlockSpec((None, block, wb), lambda g, i: (g, i, _I0)),      # prev
-            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 1, _I0)),  # cur
-            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 2, _I0)),  # next
+            pl.BlockSpec((None, block, wa), lambda g, i: (g, i, I0)),
+            pl.BlockSpec((None, block, wb), lambda g, i: (g, i, I0)),      # prev
+            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 1, I0)),  # cur
+            pl.BlockSpec((None, block, wb), lambda g, i: (g, i + 2, I0)),  # next
         ],
-        out_specs=pl.BlockSpec((None, block, wc), lambda g, i: (g, i, _I0)),
+        out_specs=pl.BlockSpec((None, block, wc), lambda g, i: (g, i, I0)),
         out_shape=jax.ShapeDtypeStruct((G, npad, wc), dtype),
         interpret=interpret,
     )(a_p, bz, bz, bz)

@@ -27,9 +27,10 @@ from ..masking import canonical_band, mask_rows
 __all__ = ["banded_matvec_pallas"]
 
 DEF_BLOCK = 512
-# int32 block index: under x64 a literal 0 in an index map is int64, which
-# Mosaic refuses
-_I0 = np.int32(0)
+# int32 block index for Pallas index maps (shared by the kernels of this
+# package): under x64 a literal 0 in an index map is int64, which Mosaic
+# refuses
+I0 = np.int32(0)
 
 
 def _kernel(band_ref, xp_ref, xc_ref, xn_ref, o_ref, *, lo, hi, block):
@@ -80,12 +81,12 @@ def banded_matvec_pallas(band: jax.Array, x: jax.Array, lo: int, hi: int,
         functools.partial(_kernel, lo=lo, hi=hi, block=block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block, w), lambda g, i: (g, i, _I0)),
-            pl.BlockSpec((None, block, B), lambda g, i: (g, i, _I0)),      # prev
-            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 1, _I0)),  # cur
-            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 2, _I0)),  # next
+            pl.BlockSpec((None, block, w), lambda g, i: (g, i, I0)),
+            pl.BlockSpec((None, block, B), lambda g, i: (g, i, I0)),      # prev
+            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 1, I0)),  # cur
+            pl.BlockSpec((None, block, B), lambda g, i: (g, i + 2, I0)),  # next
         ],
-        out_specs=pl.BlockSpec((None, block, B), lambda g, i: (g, i, _I0)),
+        out_specs=pl.BlockSpec((None, block, B), lambda g, i: (g, i, I0)),
         out_shape=jax.ShapeDtypeStruct((G, npad, B), dtype),
         interpret=interpret,
     )(band_p, xz, xz, xz)

@@ -16,17 +16,14 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from ..core.matern import _poly_coeffs
+from .banded_matvec import I0
 
 __all__ = ["kp_gram_pallas"]
 
 DEF_BLOCK = 512
-# int32 block index: under x64 a literal 0 in an index map is int64, which
-# Mosaic refuses
-_I0 = np.int32(0)
 
 
 def _matern(q, omega, r):
@@ -79,13 +76,13 @@ def kp_gram_pallas(q: int, omega, xs: jax.Array, a_band: jax.Array,
         functools.partial(_kernel, q=q, block=block, n=n),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (_I0, _I0)),
-            pl.BlockSpec((block, wA), lambda i: (i, _I0)),
-            pl.BlockSpec((block, 1), lambda i: (i, _I0)),
-            pl.BlockSpec((block, 1), lambda i: (i + 1, _I0)),
-            pl.BlockSpec((block, 1), lambda i: (i + 2, _I0)),
+            pl.BlockSpec((1, 1), lambda i: (I0, I0)),
+            pl.BlockSpec((block, wA), lambda i: (i, I0)),
+            pl.BlockSpec((block, 1), lambda i: (i, I0)),
+            pl.BlockSpec((block, 1), lambda i: (i + 1, I0)),
+            pl.BlockSpec((block, 1), lambda i: (i + 2, I0)),
         ],
-        out_specs=pl.BlockSpec((block, 2 * q + 1), lambda i: (i, _I0)),
+        out_specs=pl.BlockSpec((block, 2 * q + 1), lambda i: (i, I0)),
         out_shape=jax.ShapeDtypeStruct((npad, 2 * q + 1), a_band.dtype),
         interpret=interpret,
     )(om, a_p, xz, xz, xz)

@@ -25,7 +25,9 @@ Selection precedence (first wins):
 
        * 64-bit operands resolve to ``"jax"`` on every platform: Mosaic, the
          Pallas TPU compiler, has no 64-bit types;
-       * otherwise ``"pallas"`` on a TPU and ``"jax"`` elsewhere.
+       * otherwise ``"pallas"`` on a TPU once every kernel the backend
+         routes to lowers there (``TPU_UNLOWERED`` is empty), and ``"jax"``
+         until then and everywhere else.
 
      An explicit ``"pallas"`` with 64-bit operands on a TPU raises instead
      of falling back to the interpreter.
@@ -34,11 +36,11 @@ Selection precedence (first wins):
 into the GP config, so the choice is a trace-time static and jitted GP entry
 points specialize per backend (``GPConfig`` is a static/meta field).
 
-On a TPU the ``"pallas"`` backend additionally never routes to a kernel named
-in ``TPU_UNLOWERED`` — kernels the TPU compiler still refuses (pinned by the
-compile rehearsals in ``tests/test_tpu_lowering.py``). Each such op runs its
-jax-scan form instead, and the fused solve modes that need one resolve to
-``"off"`` (or raise when requested explicitly). This is a named static
+``TPU_UNLOWERED`` names the kernels the TPU compiler still refuses (pinned
+by the compile rehearsals in ``tests/test_tpu_lowering.py``). On a TPU an
+explicit ``"pallas"`` op that needs one of them raises, never substituting
+its jax-scan form, and ``"auto"`` fusion steps down past them to ``"off"``
+(an explicit fused mode that needs one raises). This is a named static
 exclusion, not a caught compile error.
 
 The pallas solve/logdet path additionally selects between two kernel
@@ -196,6 +198,16 @@ def kernel_lowers(name: str) -> bool:
     return not (on_tpu() and name in TPU_UNLOWERED)
 
 
+def require_lowered(name: str) -> None:
+    """Raise when the pallas backend would run kernel ``name`` on a TPU
+    that cannot compile it (``TPU_UNLOWERED``)."""
+    if not kernel_lowers(name):
+        raise ValueError(
+            f"backend='pallas' needs the {name} kernel, which does not lower "
+            "for the TPU yet (kernels.ops.TPU_UNLOWERED); use backend='auto' "
+            "or 'jax' (XLA)")
+
+
 def get_backend() -> str:
     """Current process-wide default backend name (may be "auto")."""
     return _backend
@@ -227,9 +239,10 @@ def resolve_backend(backend: str | None = None, dtype=None) -> str:
     default) and None defer to the process default (set_backend /
     REPRO_BACKEND); an "auto" process default resolves by the backend rule
     (module docstring): 64-bit ``dtype`` -> "jax" on every platform, else
-    "pallas" on TPU and "jax" elsewhere. ``dtype`` None means "not known
-    here" (the per-op calls on an already-baked config). A "pallas" that
-    would compile 64-bit operands on a TPU raises.
+    "pallas" on a TPU whose pallas kernels all lower and "jax" otherwise.
+    ``dtype`` None means "not known here" (the per-op calls on an
+    already-baked config). A "pallas" that would compile 64-bit operands on
+    a TPU raises.
     """
     b = backend if backend is not None else _backend
     if b not in BACKENDS:
@@ -244,7 +257,8 @@ def resolve_backend(backend: str | None = None, dtype=None) -> str:
                 f"expected one of {BACKENDS}")
     wide = dtype is not None and jnp.dtype(dtype).itemsize == 8
     if b == "auto":
-        return "pallas" if on_tpu() and not wide else "jax"
+        return ("pallas" if on_tpu() and not wide and not TPU_UNLOWERED
+                else "jax")
     if b == "pallas" and wide and on_tpu():
         raise ValueError(
             f"backend='pallas' cannot run {jnp.dtype(dtype).name} operands "
@@ -644,12 +658,12 @@ def banded_solve(band, rhs, lo: int, hi: int, pivot: bool = False,
     vec_in = rhs.shape[-1] == n and rhs.ndim == band.ndim - 1
     be = resolve_backend(backend, jnp.result_type(band, rhs))
     use_cr = be == "pallas" and resolve_solve_alg(alg, lo, hi) == "cr"
-    if (be == "jax" or (pivot and not use_cr)
-            or not kernel_lowers("block_cr" if use_cr else "banded_lu")):
+    if be == "jax" or (pivot and not use_cr):
         if n_active is not None:
             band = canonical_band(band, lo, hi, n_active)
             rhs = mask_rows(rhs, n_active, axis=-1 if vec_in else -2)
         return bd._solve_scan(bd.Banded(band, lo, hi), rhs, pivot=pivot)
+    require_lowered("block_cr" if use_cr else "banded_lu")
     rb = rhs[..., None] if vec_in else rhs
     batch, (bf, rf) = _flatten_batch((band, rb), (2, 2))
     if use_cr:
@@ -677,10 +691,10 @@ def banded_logdet(band, lo: int, hi: int, pivot: bool = False,
     bd = _core()
     be = resolve_backend(backend, band.dtype)
     use_cr = be == "pallas" and resolve_solve_alg(alg, lo, hi) == "cr"
-    if (be == "jax" or (pivot and not use_cr)
-            or not kernel_lowers("block_cr" if use_cr else "banded_lu")):
+    if be == "jax" or (pivot and not use_cr):
         band = canonical_band(band, lo, hi, n_active)
         return bd._logdet_scan(bd.Banded(band, lo, hi))
+    require_lowered("block_cr" if use_cr else "banded_lu")
     batch, (bf,) = _flatten_batch((band,), (2,))
     if use_cr:
         ld = block_cr_logdet_pallas(bf, lo, pivot=pivot,

@@ -229,8 +229,9 @@ def test_backend_selection_rules():
     """set_backend / use_backend / env override / validation."""
     assert ops.resolve_backend("jax") == "jax"
     assert ops.resolve_backend("pallas") == "pallas"
-    # auto resolves by platform
-    expected_auto = "pallas" if ops.on_tpu() else "jax"
+    # auto resolves by platform (pallas only on a TPU where it all lowers)
+    expected_auto = ("pallas" if ops.on_tpu() and not ops.TPU_UNLOWERED
+                     else "jax")
     assert ops.resolve_backend("auto") == expected_auto
     prev = ops.get_backend()
     try:
@@ -254,11 +255,16 @@ def test_backend_selection_rules():
 @pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
 def test_backend_rule_64bit_resolves_to_jax(monkeypatch, tpu):
     """"auto": 64-bit operands -> jax on every platform (Mosaic has no
-    64-bit types); 32-bit -> pallas exactly on a TPU. fit() bakes the rule
-    from the data's dtype."""
+    64-bit types); 32-bit -> pallas exactly on a TPU where every pallas
+    kernel lowers, so jax while TPU_UNLOWERED names any. fit() bakes the
+    rule from the data's dtype."""
     from repro.core import GPConfig, fit
 
     monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    assert ops.TPU_UNLOWERED
+    assert ops.resolve_backend("auto", jnp.float64) == "jax"
+    assert ops.resolve_backend("auto", jnp.float32) == "jax"
+    monkeypatch.setattr(ops, "TPU_UNLOWERED", frozenset())
     assert ops.resolve_backend("auto", jnp.float64) == "jax"
     assert ops.resolve_backend("auto", jnp.float32) == (
         "pallas" if tpu else "jax")
@@ -322,10 +328,15 @@ def test_no_kernel_wrapper_defaults_to_interpret(monkeypatch):
 
 
 def test_unlowered_kernels_are_excluded_on_tpu(monkeypatch):
-    """On a TPU no resolver routes to a TPU_UNLOWERED kernel: "auto" fusion
-    resolves off, an explicit fused mode raises, and the per-op dispatch
-    runs the jax scan in place of the excluded solve kernels."""
+    """On a TPU nothing routes to a TPU_UNLOWERED kernel: "auto" resolves
+    f32 to jax and "auto" fusion steps down to off, while an explicit fused
+    mode or an explicit pallas op that needs one raises rather than timing
+    a jax scan under the pallas name. Kernels that lower still run."""
+    from repro.core.band_inverse import inverse_band
+    from repro.core.banded import Banded
+
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert ops.resolve_backend("auto", jnp.float32) == "jax"
     widths = ((1, 1), (0, 0), (1, 1))
     assert not ops.kernel_lowers("mega_solve")
     assert ops.kernel_lowers("banded_matvec")
@@ -338,9 +349,13 @@ def test_unlowered_kernels_are_excluded_on_tpu(monkeypatch):
     rng = np.random.default_rng(5)
     band = _rand_band(rng, 16, 1, 1, jnp.float32)
     rhs = jnp.asarray(rng.standard_normal((16, 2)), jnp.float32)
-    jaxpr = str(jax.make_jaxpr(lambda b, r: ops.banded_solve(
-        b, r, 1, 1, backend="pallas"))(band, rhs))
-    assert "pallas_call" not in jaxpr
+    for alg in ("cr", "lu"):
+        with pytest.raises(ValueError, match="TPU_UNLOWERED"):
+            ops.banded_solve(band, rhs, 1, 1, backend="pallas", alg=alg)
+        with pytest.raises(ValueError, match="TPU_UNLOWERED"):
+            ops.banded_logdet(band, 1, 1, backend="pallas", alg=alg)
+    with pytest.raises(ValueError, match="TPU_UNLOWERED"):
+        inverse_band(Banded(band, 1, 1), 1, backend="pallas")
     jaxpr = str(jax.make_jaxpr(lambda b, r: ops.banded_matvec(
         b, r, 1, 1, backend="pallas"))(band, rhs))
     assert "pallas_call" in jaxpr
