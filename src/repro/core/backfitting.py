@@ -42,6 +42,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..health.verdict import classify_solve
 from ..masking import canonical_perm, mask_rows, tree_sum
 from .banded import Banded, matvec, solve
@@ -506,6 +507,7 @@ def _pcg(ops: DimOps, v: jax.Array, cfg: SolveConfig,
     return x, iters_used, resid
 
 
+@obs.scope("backfit.solve")
 def solve_mhat(ops: DimOps, v: jax.Array, cfg: SolveConfig = SolveConfig(),
                x0: jax.Array | None = None, return_info: bool = False,
                hier=None):

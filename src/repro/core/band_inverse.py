@@ -21,6 +21,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..masking import canonical_band
 from .banded import (Banded, _solve_scan, band_band_matmul, mask_band,
                      transpose)
@@ -186,6 +187,7 @@ def inverse_band_single(H: Banded, hw: int) -> Banded:
     return _blocks_to_band(Gd, Gu, Gl, H.n, hw)
 
 
+@obs.scope("band_inverse.rgf")
 def inverse_band(H: Banded, hw: int, backend: str | None = None) -> Banded:
     """Band of H^{-1}; batched over leading dims of H.data.
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..masking import mask_rows, tree_sum
 from .additive_gp import (AdditiveGP, GPConfig, fit, fit_hyperparams,
                           _phi_windows, prior_var)
@@ -80,64 +81,68 @@ def _acq_core(gp: AdditiveGP, Xq: jax.Array, beta, best_y, kind: str):
     q = gp.config.q
     D, n = gp.D, gp.n
     m = Xq.shape[0]
-    rows, vals, _ = _phi_windows(gp, Xq)          # (D, m, W)
-    rows_g, dvals, _ = _grad_windows(gp, Xq)      # same sparsity
+    with obs.scope("acq.mean"):
+        rows, vals, _ = _phi_windows(gp, Xq)          # (D, m, W)
+        rows_g, dvals, _ = _grad_windows(gp, Xq)      # same sparsity
 
-    # mean + mean gradient (sparse gathers on bY)
-    bwin = jnp.take_along_axis(gp.bY[:, None, :], rows, axis=2)
-    mu = jnp.sum(vals * bwin, axis=(0, 2))                       # (m,)
-    dmu = jnp.sum(dvals * bwin, axis=2).T                        # (m, D)
+        # mean + mean gradient (sparse gathers on bY)
+        bwin = jnp.take_along_axis(gp.bY[:, None, :], rows, axis=2)
+        mu = jnp.sum(vals * bwin, axis=(0, 2))                       # (m,)
+        dmu = jnp.sum(dvals * bwin, axis=2).T                        # (m, D)
 
-    # variance pieces
-    W = 2 * q + 2
-    hw = gp.Gband.lo
-    off = jnp.arange(W)[None, :] - jnp.arange(W)[:, None]
-    g_entries = gp.Gband.data[
-        jnp.arange(D)[:, None, None, None], rows[:, :, :, None],
-        hw + off[None, None, :, :],
-    ]                                                            # (D, m, W, W)
-    g_phi = jnp.einsum("dmab,dmb->dma", g_entries, vals)         # (G phi)|window
-    term2 = jnp.einsum("dma,dma->m", vals, g_phi)
+    with obs.scope("acq.variance"):
+        # variance pieces
+        W = 2 * q + 2
+        hw = gp.Gband.lo
+        off = jnp.arange(W)[None, :] - jnp.arange(W)[:, None]
+        g_entries = gp.Gband.data[
+            jnp.arange(D)[:, None, None, None], rows[:, :, :, None],
+            hw + off[None, None, :, :],
+        ]                                                        # (D, m, W, W)
+        g_phi = jnp.einsum("dmab,dmb->dma", g_entries, vals)     # (G phi)|window
+        term2 = jnp.einsum("dma,dma->m", vals, g_phi)
 
-    phi_dense = jnp.zeros((D, n, m), Xq.dtype)
-    d_idx = jnp.broadcast_to(jnp.arange(D)[:, None, None], rows.shape)
-    m_idx = jnp.broadcast_to(jnp.arange(m)[None, :, None], rows.shape)
-    phi_dense = phi_dense.at[d_idx, rows, m_idx].add(vals)
-    ws = solve(gp.ops.Phi, phi_dense, pivot=gp.config.pivot,
-               backend=gp.config.backend,
-               alg=gp.config.solve_alg)                         # sorted
-    w = gp.ops.from_sorted(ws)
-    z = solve_mhat(gp.ops, w, gp.config.solve_cfg(), hier=gp.hier)
-    # fixed-association reduction over the (D, capacity) axes: the zero tail
-    # collapses bitwise, so the padded acquisition variance equals the
-    # unpadded one bit-for-bit at any capacity tier (and under any vmap)
-    term3 = tree_sum(tree_sum(w * z, axis=1), axis=0)
-    var = jnp.maximum(prior_var(gp, Xq.dtype) - term2 + term3, 1e-12)
+        phi_dense = jnp.zeros((D, n, m), Xq.dtype)
+        d_idx = jnp.broadcast_to(jnp.arange(D)[:, None, None], rows.shape)
+        m_idx = jnp.broadcast_to(jnp.arange(m)[None, :, None], rows.shape)
+        phi_dense = phi_dense.at[d_idx, rows, m_idx].add(vals)
+        ws = solve(gp.ops.Phi, phi_dense, pivot=gp.config.pivot,
+                   backend=gp.config.backend,
+                   alg=gp.config.solve_alg)                         # sorted
+        w = gp.ops.from_sorted(ws)
+        z = solve_mhat(gp.ops, w, gp.config.solve_cfg(), hier=gp.hier)
+        # fixed-association reduction over the (D, capacity) axes: the zero
+        # tail collapses bitwise, so the padded acquisition variance equals
+        # the unpadded one bit-for-bit at any capacity tier (and any vmap)
+        term3 = tree_sum(tree_sum(w * z, axis=1), axis=0)
+        var = jnp.maximum(prior_var(gp, Xq.dtype) - term2 + term3, 1e-12)
 
-    # variance gradient: dvar/dx_d = -2 dphi^T (G phi) + 2 dphi^T Phi^{-T} z
-    y_s = solve(transpose(gp.ops.Phi), gp.ops.to_sorted(z),
-                pivot=gp.config.pivot, backend=gp.config.backend,
-                alg=gp.config.solve_alg)
-    ywin = y_s[d_idx, rows, m_idx]  # (D, m, W): y_s[d, rows[d,m,w], m]
-    dvar = (-2.0 * jnp.einsum("dma,dma->dm", dvals, g_phi)
-            + 2.0 * jnp.einsum("dma,dma->dm", dvals, ywin)).T    # (m, D)
+    with obs.scope("acq.grad"):
+        # variance gradient:
+        # dvar/dx_d = -2 dphi^T (G phi) + 2 dphi^T Phi^{-T} z
+        y_s = solve(transpose(gp.ops.Phi), gp.ops.to_sorted(z),
+                    pivot=gp.config.pivot, backend=gp.config.backend,
+                    alg=gp.config.solve_alg)
+        ywin = y_s[d_idx, rows, m_idx]  # (D, m, W): y_s[d, rows[d,m,w], m]
+        dvar = (-2.0 * jnp.einsum("dma,dma->dm", dvals, g_phi)
+                + 2.0 * jnp.einsum("dma,dma->dm", dvals, ywin)).T    # (m, D)
 
-    if kind == "ucb":
-        sqrt_s = jnp.sqrt(var)
-        val = mu + beta * sqrt_s
-        grad = dmu + (beta / (2.0 * sqrt_s))[:, None] * dvar
-    elif kind == "ei":
-        sqrt_s = jnp.sqrt(var)
-        imp = mu - best_y
-        zz = imp / sqrt_s
-        pdf = jnp.exp(-0.5 * zz**2) / jnp.sqrt(2.0 * jnp.pi)
-        cdf = 0.5 * (1.0 + jax.scipy.special.erf(zz / jnp.sqrt(2.0)))
-        val = imp * cdf + sqrt_s * pdf
-        dval_dmu = cdf
-        dval_ds = pdf / (2.0 * sqrt_s)
-        grad = dval_dmu[:, None] * dmu + dval_ds[:, None] * dvar
-    else:
-        raise ValueError(kind)
+        if kind == "ucb":
+            sqrt_s = jnp.sqrt(var)
+            val = mu + beta * sqrt_s
+            grad = dmu + (beta / (2.0 * sqrt_s))[:, None] * dvar
+        elif kind == "ei":
+            sqrt_s = jnp.sqrt(var)
+            imp = mu - best_y
+            zz = imp / sqrt_s
+            pdf = jnp.exp(-0.5 * zz**2) / jnp.sqrt(2.0 * jnp.pi)
+            cdf = 0.5 * (1.0 + jax.scipy.special.erf(zz / jnp.sqrt(2.0)))
+            val = imp * cdf + sqrt_s * pdf
+            dval_dmu = cdf
+            dval_ds = pdf / (2.0 * sqrt_s)
+            grad = dval_dmu[:, None] * dmu + dval_ds[:, None] * dvar
+        else:
+            raise ValueError(kind)
     return val, grad, mu, var
 
 

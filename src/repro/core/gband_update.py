@@ -101,6 +101,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels import ops as _kops
 from ..kernels.cr_jax import block_cr_solve_jax
 from ..masking import canonical_band
@@ -387,6 +388,7 @@ def _add_patch_band(Gdata: jax.Array, corr: jax.Array,
     return Gdata.at[d, rows].add(corr)
 
 
+@obs.scope("gband.update")
 def gband_insert(Hband_old: Banded, A: Banded, Phi: Banded,
                  Gband_old: Banded, p: jax.Array, k_new, q: int, *,
                  backend: str | None = None,
@@ -417,6 +419,7 @@ def gband_insert(Hband_old: Banded, A: Banded, Phi: Banded,
     return (Banded(Gnew, h, h, k_new), Banded(Hnew, h, h, k_new), drift)
 
 
+@obs.scope("gband.update")
 def gband_evict(Hband_old: Banded, A: Banded, Phi: Banded,
                 Gband_old: Banded, p: jax.Array, k_new, q: int, *,
                 backend: str | None = None,

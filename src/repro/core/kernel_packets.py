@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import matern as mk
 from .banded import Banded, mask_band
 
@@ -158,6 +159,7 @@ def _kp_build_rows(q: int, omega, xw, vw, psign, asign, naux):
 
 
 @partial(jax.jit, static_argnums=0)
+@obs.scope("kp.build")
 def kp_coefficient_rows(q: int, omega, xs: jax.Array, rows: jax.Array,
                         n_active=None) -> jax.Array:
     """KP coefficient rows (len(rows), 2q+3) for a subset of row indices.
@@ -190,6 +192,7 @@ def kp_coefficients(q: int, omega, xs: jax.Array) -> Banded:
     return mask_band(Banded(data, q + 1, q + 1))
 
 
+@obs.scope("kp.build")
 def gram_band_rows(kfun, xs: jax.Array, a_rows: jax.Array, rows: jax.Array,
                    loA: int, hiA: int, hw: int, n_active=None) -> jax.Array:
     """Rows of the band of Phi = A @ K restricted to ``rows``.
@@ -265,6 +268,7 @@ def query_window_start(xs: jax.Array, xq: jax.Array,
 
 
 @partial(jax.jit, static_argnums=0)
+@obs.scope("kp.windows")
 def phi_at(q: int, omega, xs: jax.Array, A: Banded, xq: jax.Array,
            n_active=None):
     """Sparse KP vector phi(x*) = A k(X, x*): values + row indices.
@@ -304,6 +308,7 @@ def phi_at(q: int, omega, xs: jax.Array, A: Banded, xq: jax.Array,
 
 
 @partial(jax.jit, static_argnums=0)
+@obs.scope("kp.windows")
 def phi_grad_at(q: int, omega, xs: jax.Array, A: Banded, xq: jax.Array,
                 n_active=None):
     """d phi(x*) / d x*: same sparsity pattern as phi_at."""

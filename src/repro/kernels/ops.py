@@ -106,6 +106,7 @@ from .banded_matvec import banded_matvec_pallas
 from .block_cr import block_cr_logdet_pallas, block_cr_solve_pallas
 from .fused_sweep import fused_vmem_bytes
 from .kp_gram import kp_gram_pallas
+from .. import obs
 from ..masking import canonical_band, mask_rows
 
 __all__ = [
@@ -616,6 +617,7 @@ def _flatten_batch(arrs, core_dims):
     return batch, flats
 
 
+@obs.scope("banded.matvec")
 def banded_matvec(band, x, lo: int, hi: int, block: int = 512,
                   backend: str | None = None, n_active=None):
     """y = M x. band (..., n, lo+hi+1); x (..., n) or (..., n, k).
@@ -640,6 +642,7 @@ def banded_matvec(band, x, lo: int, hi: int, block: int = 512,
     return out if mat_form else out[..., 0]
 
 
+@obs.scope("banded.solve")
 def banded_solve(band, rhs, lo: int, hi: int, pivot: bool = False,
                  backend: str | None = None, alg: str | None = None,
                  n_active=None):
@@ -676,6 +679,7 @@ def banded_solve(band, rhs, lo: int, hi: int, pivot: bool = False,
     return out[..., 0] if vec_in else out
 
 
+@obs.scope("banded.logdet")
 def banded_logdet(band, lo: int, hi: int, pivot: bool = False,
                   backend: str | None = None, alg: str | None = None,
                   n_active=None):
@@ -706,6 +710,7 @@ def banded_logdet(band, lo: int, hi: int, pivot: bool = False,
     return ld.reshape(batch)
 
 
+@obs.scope("banded.matmul")
 def band_band_matmul(a_band, b_band, a_lo: int, a_hi: int, b_lo: int,
                      b_hi: int, block: int = 512, backend: str | None = None,
                      n_active=None):
@@ -729,6 +734,7 @@ def band_band_matmul(a_band, b_band, a_lo: int, a_hi: int, b_lo: int,
     return out * bd._band_mask(n, a_lo + b_lo, a_hi + b_hi)
 
 
+@obs.scope("kp.build")
 def kp_gram(q: int, omega, xs, a_band, block: int = 512,
             backend: str | None = None):
     """Fused Phi = A K band assembly (Algorithm 2)."""

@@ -29,6 +29,7 @@ forever.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from functools import partial
 
@@ -36,11 +37,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.additive_gp import AdditiveGP, with_capacity
 from ..core.bayesopt import BOConfig, acquisition_stats, ascent_step
 from ..health import verdict as hv
-from .updates import (evict as stream_evict, insert as stream_insert,
-                      resync_gband)
+from .updates import (_evict_impl, _insert_impl, evict as stream_evict,
+                      insert as stream_insert, resync_gband)
 
 __all__ = ["GPServeEngine", "Query", "propose_via_engine"]
 
@@ -70,6 +72,8 @@ class Query:
     # owning tenant id when served by the multi-tenant GPFleetEngine (the
     # single-GP engine leaves it 0)
     tenant: int = 0
+    # time.perf_counter at submit(): the start of its ``engine.queued`` wait
+    submitted: float = 0.0
 
 
 @partial(jax.jit, static_argnames=("kind",))
@@ -105,6 +109,9 @@ class GPServeEngine:
         self.window = window
         self.gp = with_capacity(gp, max(capacity, gp.n))
         self.bounds = jnp.asarray(bounds)
+        # the ascent box and step, on the device once (not per tick)
+        self._lo, self._hi = self.bounds[:, 0], self.bounds[:, 1]
+        self._step_len = lr * (self._hi - self._lo)
         self.B = batch_slots
         self.kind = kind
         self.beta = beta
@@ -159,7 +166,9 @@ class GPServeEngine:
         h = self.gp.health
         if h is None:
             return
-        verdict, drift, muts = jax.device_get((h.verdict, h.drift, h.muts))
+        with obs.span("fence.health"):
+            verdict, drift, muts = jax.device_get((h.verdict, h.drift,
+                                                   h.muts))
         if float(drift) > hv.DRIFT_TOL or int(muts) >= hv.RESYNC_EVERY:
             from ..health.ladder import HealthEvent
 
@@ -208,31 +217,59 @@ class GPServeEngine:
         if kind not in ("mean", "var", "acq", "ascend"):
             raise ValueError(f"unknown query kind {kind!r}")
         q = Query(rid=self._next_rid, x=np.asarray(x, self._xs.dtype),
-                  kind=kind, steps=steps if kind == "ascend" else 0)
+                  kind=kind, steps=steps if kind == "ascend" else 0,
+                  submitted=time.perf_counter())
         self._next_rid += 1
         self.pending.append(q)
         return q
 
+    def stats(self) -> dict:
+        """The engine counters of :mod:`repro.obs` (process-wide, summed
+        over every engine of the process) and, under ``"compiled"``, how
+        many programs each of the tick, insert and evict steps holds: a
+        number that grows in steady serving names the step that retraced."""
+        out = {k: v for k, v in obs.counters().items()
+               if k.startswith("engine.")}
+        out["compiled"] = {"engine_step": _engine_step._cache_size(),
+                           "insert": _insert_impl._cache_size(),
+                           "evict": _evict_impl._cache_size()}
+        return out
+
     def step(self) -> list[Query]:
         """One engine tick; returns the queries retired this tick."""
+        with obs.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> list[Query]:
         if self._staged and all(s is None for s in self.slots):
             self._apply_staged()
         if not self._staged:  # staged mutations fence admission
-            for i in range(self.B):
-                if self.slots[i] is None and self.pending:
-                    q = self.pending.popleft()
-                    q.version = self.version
-                    self.slots[i] = q
-                    self._xs[i] = q.x
-                    self._besty[i] = self.best_y
+            with obs.span("engine.admit"):
+                now = time.perf_counter()
+                for i in range(self.B):
+                    if self.slots[i] is None and self.pending:
+                        q = self.pending.popleft()
+                        q.version = self.version
+                        self.slots[i] = q
+                        self._xs[i] = q.x
+                        self._besty[i] = self.best_y
+                        obs.record("engine.queued", q.submitted, now)
+                        obs.count("engine.admitted")
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return []
-        lo, hi = self.bounds[:, 0], self.bounds[:, 1]
-        out = _engine_step(self.gp, jnp.asarray(self._xs), self.beta,
-                           jnp.asarray(self._besty), lo, hi,
-                           self.lr * (hi - lo), self.kind)
-        val, grad, mu, var, Xn = map(np.asarray, out)
+        obs.count("engine.ticks")
+        obs.count("engine.slot_ticks", len(active))
+        with obs.span("engine.dispatch"):
+            out = _engine_step(self.gp, jnp.asarray(self._xs), self.beta,
+                               jnp.asarray(self._besty), self._lo, self._hi,
+                               self._step_len, self.kind)
+        with obs.span("engine.fetch"):
+            val, grad, mu, var, Xn = map(np.asarray, out)
+        with obs.span("engine.retire"):
+            return self._retire(active, val, grad, mu, var, Xn)
+
+    def _retire(self, active, val, grad, mu, var, Xn) -> list[Query]:
         # query-path detection (health-on posteriors only): a nonfinite
         # result means a corrupt artifact reached serving. Hold the affected
         # slots (no retire, no ascend advance), ladder-repair the posterior,
@@ -306,45 +343,54 @@ class GPServeEngine:
         self._staged.append(("set", gp))
 
     def _apply_staged(self) -> None:
-        for op in self._staged:
-            if op[0] == "insert":
-                # sliding window: free oldest slots first — capacity, and
-                # therefore the compiled steps, never grow. A loop (not a
-                # single evict) so an engine constructed *above* the window
-                # drains down to it instead of staying pinned forever.
-                while self.window is not None and self._count >= self.window:
-                    self.gp = stream_evict(self.gp, iters=self.insert_iters,
-                                           count=self._count)
-                    self._count -= 1
-                    self.version += 1
-                if self._count >= self.gp.n:
-                    # tier overflow: re-home into a doubled allocation (one
-                    # new trace per tier; no version bump — same posterior)
-                    self.gp = with_capacity(self.gp, _next_tier(2 * self.gp.n))
+        with obs.span("engine.fence"):
+            obs.count("engine.fences")
+            for op in self._staged:
+                self._apply(op)
+            self._staged.clear()
+            self._post_mutation_health()
+            with obs.span("fence.best_y"):
+                self.best_y = float(jnp.max(self._active_y()))
+
+    def _apply(self, op: tuple) -> None:
+        if op[0] == "insert":
+            # sliding window: free oldest slots first — capacity, and
+            # therefore the compiled steps, never grow. A loop (not a
+            # single evict) so an engine constructed *above* the window
+            # drains down to it instead of staying pinned forever.
+            while self.window is not None and self._count >= self.window:
+                self._evict_one()
+            if self._count >= self.gp.n:
+                # tier overflow: re-home into a doubled allocation (one
+                # new trace per tier; no version bump — same posterior)
+                self.gp = with_capacity(self.gp, _next_tier(2 * self.gp.n))
+            with obs.span("fence.insert"):
                 self.gp = stream_insert(self.gp, op[1], op[2],
                                         iters=self.insert_iters,
                                         count=self._count)
-                self._count += 1
-                self.version += 1
-            elif op[0] == "evict":
-                self.gp = stream_evict(self.gp, iters=self.insert_iters,
-                                       count=self._count)
-                self._count -= 1
-                self.version += 1
-            else:
-                gp = op[1]
-                # keep the tier: re-home the replacement into (at least) the
-                # current capacity so the compiled step stays warm — but
-                # never below the replacement's own allocation (a pre-padded
-                # fit may already be larger; capacity cannot shrink)
-                self.gp = with_capacity(
-                    gp, max(self.gp.n, gp.n,
-                            _next_tier(gp.num_points() + 1)))
-                self._count = gp.num_points()
-                self.version += 1
-        self._staged.clear()
-        self._post_mutation_health()
-        self.best_y = float(jnp.max(self._active_y()))
+            self._count += 1
+        elif op[0] == "evict":
+            self._evict_one()
+            return
+        else:
+            gp = op[1]
+            # keep the tier: re-home the replacement into (at least) the
+            # current capacity so the compiled step stays warm — but
+            # never below the replacement's own allocation (a pre-padded
+            # fit may already be larger; capacity cannot shrink)
+            self.gp = with_capacity(
+                gp, max(self.gp.n, gp.n, _next_tier(gp.num_points() + 1)))
+            self._count = gp.num_points()
+        self.version += 1
+        obs.count("engine.mutations")
+
+    def _evict_one(self) -> None:
+        with obs.span("fence.evict"):
+            self.gp = stream_evict(self.gp, iters=self.insert_iters,
+                                   count=self._count)
+        self._count -= 1
+        self.version += 1
+        obs.count("engine.mutations")
 
 
 def propose_via_engine(engine: GPServeEngine, key: jax.Array, cfg: BOConfig,

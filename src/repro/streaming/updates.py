@@ -56,6 +56,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from .. import obs
 from ..core import matern as mk
 from ..core.additive_gp import (AdditiveGP, TIE_EPS, build_gp_hier,
                                 mean_caches, with_capacity)
@@ -215,11 +216,12 @@ def _insert_core(gp: AdditiveGP, x_new: jax.Array, y_new: jax.Array,
     q = config.q
     C = gp.n
     k = jnp.asarray(gp.active(), jnp.int32)
-    xs, sort_idx, rank_idx, a, phi, b, psi, p = jax.vmap(
-        lambda om, xd, sd, rd, ad, pd, bd, qd, xv: _insert_dim(
-            q, k, om, xd, sd, rd, ad, pd, bd, qd, xv)
-    )(gp.omega, gp.xs, gp.ops.sort_idx, gp.ops.rank_idx, gp.ops.A.data,
-      gp.ops.Phi.data, gp.B.data, gp.Psi.data, x_new)
+    with obs.scope("mutation.splice"):
+        xs, sort_idx, rank_idx, a, phi, b, psi, p = jax.vmap(
+            lambda om, xd, sd, rd, ad, pd, bd, qd, xv: _insert_dim(
+                q, k, om, xd, sd, rd, ad, pd, bd, qd, xv)
+        )(gp.omega, gp.xs, gp.ops.sort_idx, gp.ops.rank_idx, gp.ops.A.data,
+          gp.ops.Phi.data, gp.B.data, gp.Psi.data, x_new)
     k1 = k + 1
     A = Banded(a, q + 1, q + 1, k1)
     Phi = Banded(phi, q, q, k1)
@@ -384,11 +386,12 @@ def _evict_core(gp: AdditiveGP, iters: int) -> AdditiveGP:
     q = config.q
     k = jnp.asarray(gp.active(), jnp.int32)
     p = gp.ops.rank_idx[:, 0]  # sorted position of the oldest point, per dim
-    xs, sort_idx, rank_idx, a, phi, b, psi = jax.vmap(
-        lambda om, xd, sd, rd, ad, pd, bd, qd, pp: _evict_dim(
-            q, k, om, xd, sd, rd, ad, pd, bd, qd, pp)
-    )(gp.omega, gp.xs, gp.ops.sort_idx, gp.ops.rank_idx, gp.ops.A.data,
-      gp.ops.Phi.data, gp.B.data, gp.Psi.data, p)
+    with obs.scope("mutation.splice"):
+        xs, sort_idx, rank_idx, a, phi, b, psi = jax.vmap(
+            lambda om, xd, sd, rd, ad, pd, bd, qd, pp: _evict_dim(
+                q, k, om, xd, sd, rd, ad, pd, bd, qd, pp)
+        )(gp.omega, gp.xs, gp.ops.sort_idx, gp.ops.rank_idx, gp.ops.A.data,
+          gp.ops.Phi.data, gp.B.data, gp.Psi.data, p)
     k1 = k - 1
     A = Banded(a, q + 1, q + 1, k1)
     Phi = Banded(phi, q, q, k1)
