@@ -5,7 +5,9 @@ matmul, KP Gram assembly — routes through this module and is served by one
 of two backends:
 
   * ``"jax"``    — the pure-jax ``lax.scan`` reference implementations in
-                   ``repro.core.banded`` (compiled by XLA, CPU/GPU/TPU).
+                   ``repro.core.banded`` (compiled by XLA, CPU/GPU/TPU),
+                   and for long unpivoted symmetric-band solves the
+                   log-depth block cyclic reduction of ``cr_jax.py``.
   * ``"pallas"`` — the Pallas TPU kernels in this package. Whether they run
                    compiled or in the interpreter is decided in one place,
                    ``interpret_kernels()``: interpreted exactly when no TPU
@@ -58,6 +60,16 @@ algorithms (``REPRO_SOLVE_ALG`` env / ``set_solve_alg`` / the per-op
 algorithm is ``"cr"``; only the asymmetric-bandwidth (or forced-``"lu"``)
 pivoted case still falls back to the jax gbsv-style scan.
 
+The same ``"cr"``/``"lu"`` choice applies to the jax backend's unpivoted
+solves: a band with ``lo == hi >= 1`` and at least ``CR_MIN_BLOCK_ROWS``
+block rows (``n / w``) solves by the pure-jax block cyclic reduction
+(``cr_jax.block_cr_solve_jax``) when the alg resolves to ``"cr"``, in
+ceil(log2(n/w)) vectorized levels each way instead of n sequential scan
+steps. Shorter systems, ``pivot=True``, ``lo != hi``, diagonal bands and
+``"lu"`` keep the scan LU; so do the logdet and the RGF band inverse. The
+``solve.cr`` / ``solve.scan`` counters of ``repro.obs`` count, at trace
+time, the solves that took each route.
+
 Batched operands (the GP's stacked per-dimension factors, leading dims)
 are flattened and folded into the kernel **grid** for every pallas kernel
 (``_flatten_batch`` -> one ``pallas_call``); no op unrolls its batch at
@@ -104,6 +116,7 @@ from .band_matmul import band_matmul_pallas
 from .banded_lu import banded_logdet_pallas, banded_solve_pallas
 from .banded_matvec import banded_matvec_pallas
 from .block_cr import block_cr_logdet_pallas, block_cr_solve_pallas
+from .cr_jax import block_cr_solve_jax
 from .fused_sweep import fused_vmem_bytes
 from .kp_gram import kp_gram_pallas
 from .. import obs
@@ -147,6 +160,16 @@ ENV_HEALTH = "REPRO_HEALTH"
 # spectral range and the coarse correction stops resembling the fine
 # operator (see kernels/README.md)
 KMG_AUTO_MIN_N = 4096
+
+# jax-backend unpivoted solves of a band with lo == hi >= 1 run the
+# log-depth block cyclic reduction of ``cr_jax`` (when the solve alg
+# resolves to "cr") from this many block rows (n / w) up, and the
+# sequential scan LU below it. On a TPU v5e (float64, D = 10, B = 32; the
+# table in PERF.md) CR was faster from 16 block rows, but by 13-43 us a
+# solve (1.2-1.6x) below 64, for about twice the scan's code; from 64 up it
+# is 2.5-13x. 64 also keeps the tiny systems of the CPU tests (capacity-16
+# fleets and padding checks) on the scan.
+CR_MIN_BLOCK_ROWS = 64
 
 def _env_mode(var: str, valid: tuple[str, ...]) -> str:
     """Read a mode env var, failing *at import* on an invalid value.
@@ -650,6 +673,9 @@ def banded_solve(band, rhs, lo: int, hi: int, pivot: bool = False,
 
     On the pallas backend ``alg`` picks the kernel ("cr" block cyclic
     reduction when ``lo == hi`` — the default — vs "lu" row recurrence).
+    On the jax backend "cr" means the log-depth ``cr_jax`` solve for an
+    unpivoted ``lo == hi >= 1`` band of at least ``CR_MIN_BLOCK_ROWS``
+    block rows; every other jax solve is the scan LU.
     ``pivot=True`` runs the pivoted block-CR kernel when the resolved
     algorithm is "cr"; otherwise it falls back to the jax gbsv-style scan
     (there is no pivoted LU kernel). With ``n_active`` the padded system is
@@ -665,6 +691,17 @@ def banded_solve(band, rhs, lo: int, hi: int, pivot: bool = False,
         if n_active is not None:
             band = canonical_band(band, lo, hi, n_active)
             rhs = mask_rows(rhs, n_active, axis=-1 if vec_in else -2)
+        if (be == "jax" and not pivot and lo == hi >= 1
+                and -(-n // lo) >= CR_MIN_BLOCK_ROWS
+                and resolve_solve_alg(alg, lo, hi) == "cr"):
+            obs.count("solve.cr")
+            rb = rhs[..., None] if vec_in else rhs
+            batch = jnp.broadcast_shapes(band.shape[:-2], rb.shape[:-2])
+            x = block_cr_solve_jax(
+                jnp.broadcast_to(band, batch + band.shape[-2:]),
+                jnp.broadcast_to(rb, batch + rb.shape[-2:]), lo, pivot=False)
+            return x[..., 0] if vec_in else x
+        obs.count("solve.scan")
         return bd._solve_scan(bd.Banded(band, lo, hi), rhs, pivot=pivot)
     require_lowered("block_cr" if use_cr else "banded_lu")
     rb = rhs[..., None] if vec_in else rhs

@@ -224,12 +224,15 @@ class GPServeEngine:
         return q
 
     def stats(self) -> dict:
-        """The engine counters of :mod:`repro.obs` (process-wide, summed
-        over every engine of the process) and, under ``"compiled"``, how
-        many programs each of the tick, insert and evict steps holds: a
-        number that grows in steady serving names the step that retraced."""
+        """The engine and solve-route counters of :mod:`repro.obs`
+        (process-wide, summed over every engine of the process) and, under
+        ``"compiled"``, how many programs each of the tick, insert and evict
+        steps holds: a number that grows in steady serving names the step
+        that retraced. ``solve.cr`` / ``solve.scan`` count, per traced
+        program, the banded solves that took block cyclic reduction or the
+        scan LU (``kernels.ops.banded_solve``)."""
         out = {k: v for k, v in obs.counters().items()
-               if k.startswith("engine.")}
+               if k.startswith(("engine.", "solve."))}
         out["compiled"] = {"engine_step": _engine_step._cache_size(),
                            "insert": _insert_impl._cache_size(),
                            "evict": _evict_impl._cache_size()}

@@ -470,3 +470,197 @@ def test_gp_mll_backend_parity():
     assert np.abs(out["jax"][1] - out["pallas"][1]).max() < 1e-6
     assert abs(out["jax"][2] - out["pallas"][2]) < 1e-6
     assert np.abs(out["jax"][3] - out["pallas"][3]).max() < 1e-7
+
+
+# --- jax backend: block cyclic reduction above the crossover --------------
+#
+# ``ops.banded_solve`` on the jax backend runs ``cr_jax.block_cr_solve_jax``
+# for unpivoted lo == hi >= 1 bands of at least ``ops.CR_MIN_BLOCK_ROWS``
+# block rows and the sequential scan LU for everything else. Kept small in
+# D and B: each CR instance is a compile of its own on the CPU.
+
+
+def _loops(jaxpr):
+    """(primitive, trip count) of every loop in a jaxpr, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(("scan", eqn.params["length"]))
+        elif eqn.primitive.name == "while":
+            out.append(("while", None))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(sub, "jaxpr"):
+                    out += _loops(getattr(sub.jaxpr, "jaxpr", sub.jaxpr))
+                elif hasattr(sub, "eqns"):
+                    out += _loops(sub)
+    return out
+
+
+def _route(band, rhs, lo, hi, **kw):
+    """Trace one jax-backend solve; return (its loops, solve.* counts added
+    while tracing it)."""
+    from repro import obs
+
+    before = obs.counters()
+    jaxpr = jax.make_jaxpr(lambda b, r: ops.banded_solve(
+        b, r, lo, hi, backend="jax", **kw))(band, rhs)
+    after = obs.counters()
+    moved = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("solve.cr", "solve.scan")}
+    return _loops(jaxpr.jaxpr), moved
+
+
+def _dense_solve(band, rhs, w):
+    dense = np.asarray(bd.to_dense(bd.Banded(band, w, w)))
+    r = np.asarray(rhs)
+    vec = r.ndim == band.ndim - 1
+    out = np.linalg.solve(dense, r[..., None] if vec else r)
+    return out[..., 0] if vec else out
+
+
+@pytest.mark.parametrize("form", ["vec", "batched"])
+@pytest.mark.parametrize("w", [1, 2, 3])
+def test_jax_cr_route_above_crossover(w, form):
+    """Above the crossover the unpivoted solve is block CR (``solve.cr``
+    moves): two loops of ceil(log2(n/w)) trips, the reduction and the back
+    substitution, where the scan LU runs two of n; within 1e-10 of the
+    dense oracle and 1e-12 of the scan LU (``alg="lu"``); n is not a
+    multiple of w, so CR's own block padding is exercised too."""
+    n = w * ops.CR_MIN_BLOCK_ROWS + (w - 1)
+    rng = np.random.default_rng(40 + w)
+    batch = (2,) if form == "batched" else ()
+    band = _rand_band(rng, n, w, w, jnp.float64, batch,
+                      boost=4.0 * (2 * w + 1))
+    shape = batch + ((n, 3) if form == "batched" else (n,))
+    rhs = jnp.asarray(rng.standard_normal(shape))
+    loops, moved = _route(band, rhs, w, w)
+    depth = (-(-n // w) - 1).bit_length()
+    assert loops == [("scan", depth)] * 2
+    assert moved == {"solve.cr": 1, "solve.scan": 0}
+    got = jax.jit(lambda b, r: ops.banded_solve(b, r, w, w, backend="jax"))(
+        band, rhs)
+    lu = jax.jit(lambda b, r: ops.banded_solve(
+        b, r, w, w, backend="jax", alg="lu"))(band, rhs)
+    want = _dense_solve(band, rhs, w)
+    assert got.shape == rhs.shape
+    scale = np.max(np.abs(want))
+    assert np.max(np.abs(np.asarray(got) - want)) <= 1e-10 * scale
+    assert np.max(np.abs(np.asarray(got - lu))) <= 1e-12 * scale
+
+
+@pytest.mark.parametrize("case", ["below", "pivot", "lu", "asym", "diag"])
+def test_jax_scan_route(case):
+    """The scan LU keeps every solve the crossover does not cover: short
+    systems, ``pivot=True``, an explicit ``alg="lu"``, lo != hi and diagonal
+    bands."""
+    C = ops.CR_MIN_BLOCK_ROWS
+    lo, hi, n, kw = 1, 1, 2 * C, {}
+    if case == "below":
+        lo = hi = 2
+        n = 2 * (C - 1)
+    elif case == "pivot":
+        kw = {"pivot": True}
+    elif case == "lu":
+        kw = {"alg": "lu"}
+    elif case == "asym":
+        lo, hi = 2, 1
+    else:
+        lo = hi = 0
+    rng = np.random.default_rng(7)
+    band = _rand_band(rng, n, lo, hi, jnp.float64, (2,))
+    rhs = jnp.asarray(rng.standard_normal((2, n, 2)))
+    loops, moved = _route(band, rhs, lo, hi, **kw)
+    assert moved == {"solve.cr": 0, "solve.scan": 1}
+    if lo > 0:
+        assert ("scan", n) in loops
+
+
+def _poisoned(band, rhs, w, n_active, cap, rng):
+    """Embed (band, rhs) at capacity ``cap`` with a NaN/garbage tail."""
+    bp = np.full(band.shape[:-2] + (cap, 2 * w + 1), np.nan)
+    bp[..., :n_active, :] = np.asarray(band)
+    rp = rng.standard_normal(rhs.shape[:-2] + (cap, rhs.shape[-1])) * 1e30
+    rp[..., :n_active, :] = np.asarray(rhs)
+    return jnp.asarray(bp), jnp.asarray(rp)
+
+
+@pytest.mark.parametrize("w", [1, 2])
+def test_jax_cr_capacity_tail_invariance(w):
+    """Above the crossover a capacity-padded solve (tail poisoned before
+    canonicalisation) equals the unpadded solve bitwise on the active
+    prefix, and is exactly zero on the tail."""
+    n_active = w * ops.CR_MIN_BLOCK_ROWS + 37
+    cap = n_active + 3 * w * 64 + 5
+    rng = np.random.default_rng(90 + w)
+    band = _rand_band(rng, n_active, w, w, jnp.float64, (2,),
+                      boost=4.0 * (2 * w + 1))
+    rhs = jnp.asarray(rng.standard_normal((2, n_active, 4)))
+    bp, rp = _poisoned(band, rhs, w, n_active, cap, rng)
+    solve = jax.jit(lambda b, r, na: ops.banded_solve(
+        b, r, w, w, backend="jax", n_active=na))
+    from repro import obs
+
+    before = obs.counters().get("solve.cr", 0)
+    full = solve(bp, rp, jnp.int32(n_active))
+    plain = jax.jit(lambda b, r: ops.banded_solve(b, r, w, w, backend="jax"))(
+        band, rhs)
+    assert obs.counters().get("solve.cr", 0) - before == 2
+    np.testing.assert_array_equal(np.asarray(full[..., :n_active, :]),
+                                  np.asarray(plain))
+    np.testing.assert_array_equal(np.asarray(full[..., n_active:, :]), 0.0)
+
+
+@pytest.mark.parametrize("how", ["stacked", "vmap"])
+def test_jax_cr_lane_invariance(how):
+    """A T=4 stack of capacity-padded solves, with a different active count
+    per lane, equals each lane solved alone bitwise, as a leading batch dim
+    and under ``vmap`` (the fleet's lanes)."""
+    w, T = 1, 4
+    cap = 2 * ops.CR_MIN_BLOCK_ROWS
+    rng = np.random.default_rng(123)
+    nas = [cap, cap - 1, cap // 2 + 5, 7]
+    bands, rhss = [], []
+    for na in nas:
+        band = _rand_band(rng, na, w, w, jnp.float64, (2,), boost=12.0)
+        rhs = jnp.asarray(rng.standard_normal((2, na, 3)))
+        bp, rp = _poisoned(band, rhs, w, na, cap, rng)
+        bands.append(bp)
+        rhss.append(rp)
+    B, R = jnp.stack(bands), jnp.stack(rhss)
+    na_arr = jnp.asarray(nas, jnp.int32)
+
+    def one(b, r, na):
+        return ops.banded_solve(b, r, w, w, backend="jax", n_active=na)
+
+    stacked = (jax.jit(one)(B, R, na_arr) if how == "stacked"
+               else jax.jit(jax.vmap(one))(B, R, na_arr))
+    lane = jax.jit(one)
+    for t in range(T):
+        np.testing.assert_array_equal(
+            np.asarray(stacked[t]),
+            np.asarray(lane(bands[t], rhss[t], na_arr[t])))
+
+
+@pytest.mark.parametrize("w", [1, 2, 3])
+def test_cr_jax_rolled_levels_match_compacted(w):
+    """The unpivoted ``block_cr_solve_jax`` runs its levels rolled into
+    loops over full-length arrays; it does the compacted levels' arithmetic,
+    bitwise at w = 1 (the q = 0 solves of the serving path) and w = 2, and
+    within 1e-15 relative at w = 3, on a batch of odd block counts."""
+    from repro.kernels.cr_jax import _solve_compacted, block_cr_solve_jax
+
+    rng = np.random.default_rng(60 + w)
+    for n in (1, w * 37 + 1, w * 64):
+        band = _rand_band(rng, n, w, w, jnp.float64, (2,),
+                          boost=4.0 * (2 * w + 1))
+        rhs = jnp.asarray(rng.standard_normal((2, n, 3)))
+        rolled = np.asarray(jax.jit(lambda b, r: block_cr_solve_jax(
+            b, r, w, pivot=False))(band, rhs))
+        compact = np.asarray(jax.jit(lambda b, r: _solve_compacted(
+            b, r, w, False))(band, rhs))
+        if w <= 2:
+            np.testing.assert_array_equal(rolled, compact)
+        else:
+            assert (np.max(np.abs(rolled - compact))
+                    <= 1e-15 * np.max(np.abs(compact)))

@@ -128,9 +128,13 @@ def test_engine_tick_and_fence_spans_and_counters(gp):
 
     want = {"engine.ticks": 2, "engine.slot_ticks": 5, "engine.admitted": 5,
             "engine.fences": 1, "engine.mutations": 2}
-    assert obs.counters() == want
+    # the solve-route counters move only where a program was traced
+    assert {k: v for k, v in obs.counters().items()
+            if not k.startswith("solve.")} == want
     st = eng.stats()
     assert {k: st[k] for k in want} == want
+    assert {k for k in st if k.startswith("solve.")} <= {"solve.cr",
+                                                         "solve.scan"}
     assert set(st["compiled"]) == {"engine_step", "insert", "evict"}
     assert all(v >= 1 for v in st["compiled"].values())
 
@@ -191,3 +195,29 @@ def test_compiled_programs_keep_the_core_scopes(gp, no_persistent_cache):
     for s in ("band_inverse.rgf", "mutation.splice", "kp.build",
               "backfit.solve", "banded.matmul"):
         assert f"{obs.PREFIX}{s}" in ops, s
+
+
+@pytest.mark.parametrize("program", ["tick", "insert", "evict"])
+def test_engine_programs_route_solves_to_cr(gp, program):
+    """Above ``kernels.ops.CR_MIN_BLOCK_ROWS`` block rows the engine's tick,
+    insert and evict programs run their unpivoted solves by block cyclic
+    reduction, which ``stats()`` reports as ``solve.cr``."""
+    from repro.core.additive_gp import with_capacity
+    from repro.kernels.ops import CR_MIN_BLOCK_ROWS
+    from repro.streaming.gp_engine import _engine_step
+    from repro.streaming.updates import _evict_impl
+
+    g = with_capacity(gp, 2 * N)
+    assert 2 * N >= CR_MIN_BLOCK_ROWS
+    X = jnp.asarray(_data(SLOTS, seed=3)[0])
+    lower = {
+        "tick": lambda: _engine_step.lower(
+            g, X, 2.0, 0.0, jnp.zeros(D), jnp.full(D, 5.0), 0.05, kind="ucb"),
+        "insert": lambda: _insert_impl.lower(g, X[0], jnp.asarray(0.5), 10),
+        "evict": lambda: _evict_impl.lower(g, 10),
+    }[program]
+    jax.clear_caches()  # trace afresh: the counters move at trace time
+    lower()
+    bounds = jnp.asarray([[0.0, 5.0]] * D)
+    st = GPServeEngine(gp, bounds, batch_slots=SLOTS).stats()
+    assert st.get("solve.cr", 0) >= 1
