@@ -32,10 +32,12 @@ FUNCTIONS = {"schwefel": (schwefel, 500.0), "rastrigin": (rastrigin, 5.12)}
 STREAMS = {"data": 0, "warmup": 1, "schedule": 2, "check": 3, "probes": 4}
 
 
-def rng(seed: int, stream: str) -> np.random.Generator:
+def rng(seed: int, stream: str, *key: int) -> np.random.Generator:
+    """The seed's generator for ``stream``; ``key`` (such as a round's
+    index) splits a stream into independent ones."""
     return np.random.default_rng(
         np.random.SeedSequence(int(seed) % 2 ** 64,
-                               spawn_key=(STREAMS[stream],)))
+                               spawn_key=(STREAMS[stream],) + key))
 
 
 def bounds(config: dict) -> np.ndarray:

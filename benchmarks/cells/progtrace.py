@@ -341,18 +341,22 @@ def mean_call_ms(run, scope: str) -> float | None:
     return float(np.mean(d) * 1e3) if d else None
 
 
-def per_run_ms(run, scope: str) -> float | None:
+def per_run_ms(run, scope: str, program: str | None = None) -> float | None:
     """Mean device time under ``scope`` per run of a program that holds it,
     over the program runs wholly inside the slice: the scope's own time
     summed within each run. XLA interleaves a scope's operations with
     independent work of the same program (the RGF sweep of a mutation with
     its warm solve), so where a program calls a scope once, this is its
-    time per call, and a run of consecutive ops is only a piece of it."""
+    time per call, and a run of consecutive ops is only a piece of it.
+    ``program``, where given, keeps only the programs whose name holds it
+    (the fit's PCG apart from the variance's)."""
     a = analyse(run)
     if a is None:
         return None
     per = []
-    for _, ms, me, d in a["modules"]:
+    for name, ms, me, d in a["modules"]:
+        if program is not None and program not in name:
+            continue
         if a["lo"] <= ms and me <= a["hi"]:
             t = sum(x for o, x in zip(a["ops"], a["own"])
                     if o[2] == d and o[3] == scope and ms <= o[0] < me)

@@ -81,6 +81,14 @@ class DenseGP:
                                   check_finite=False)
         self.alpha = sla.cho_solve(self.cho, self.Y, check_finite=False)
 
+    def log_marginal(self) -> float:
+        """log p(Y) = -(Y^T K^-1 Y + log|K| + n log 2 pi) / 2 (paper Eq.
+        (14)), log|K| from the Cholesky factor's diagonal."""
+        n = self.Y.shape[0]
+        logdet = 2.0 * float(np.sum(np.log(np.diag(self.cho[0]))))
+        return -0.5 * (float(self.Y @ self.alpha) + logdet
+                       + n * math.log(2.0 * math.pi))
+
     def prior_var(self) -> float:
         return float(sum(matern(self.q, om, np.zeros(1))[0] for om in self.omega))
 

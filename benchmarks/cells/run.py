@@ -43,7 +43,6 @@ sys.path.insert(0, HERE)
 import spec  # noqa: E402
 import xtrace  # noqa: E402
 
-DRIVERS = {"open_loop": "serve"}  # traffic kind -> driver module
 CACHE_DIR = os.path.join(spec.ROOT, ".jax_cache")
 WATCHDOG_S = 120.0
 TRACE_DIR = os.path.join(spec.ROOT, ".bench_trace")
@@ -114,6 +113,17 @@ class Context:
         self.spans.items.append(("window", self._window_t0, time.perf_counter()))
         self.window_compiles = self.events["compile"] - self._compiles_at_open
         self.log(f"window: {self.window_compiles} programs built inside it")
+
+    def deadline(self, seconds):
+        """End the process, with every thread's stack on stderr and a
+        non-zero exit, if it is still running ``seconds`` from now: a fetch
+        that never returns ends the run instead of hanging it. ``None``
+        puts back the watchdog of ``window_close``, which only prints.
+        faulthandler keeps one timer, so each call replaces the last."""
+        if seconds is None:
+            faulthandler.dump_traceback_later(WATCHDOG_S, repeat=True)
+        else:
+            faulthandler.dump_traceback_later(seconds, exit=True)
 
     def read_memory(self):
         import jax
@@ -188,7 +198,7 @@ def run(argv=None, require_tpu: bool = True, root: str = spec.ROOT) -> dict:
     ctx.log(f"cell {cell.name}: config {cell.config['name']}, dtype {dtype}, "
             f"seed {a.seed}, {a.seconds}s, trace {a.trace}"
             + (f", rate {a.rate}/s" if a.rate is not None else ""))
-    driver = __import__(DRIVERS[cell.traffic["kind"]])
+    driver = spec.driver(cell.traffic["kind"], root)
     out = driver.run(cell, a.seed, a.seconds, ctx)
 
     checks = out["checks"]

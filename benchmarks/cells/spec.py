@@ -2,9 +2,10 @@
 
 ``BENCHMARK.json`` at the checkout's root names each cell (``workloads``),
 its configuration (``configs[].file``) and its traffic mix; the mix is
-``traffic/<name>.json`` beside this file and each per-layer metric is read by
-``layers/<metric>.py``. Adding a cell, a mix or a metric is adding files:
-nothing here lists them.
+``traffic/<name>.json`` beside this file, the mix's ``kind`` names the
+driver that runs it, ``<kind>.py`` beside this file, and each per-layer
+metric is read by ``layers/<metric>.py``. Adding a cell, a mix, a kind of
+traffic or a metric is adding files: nothing here lists them.
 """
 from __future__ import annotations
 
@@ -12,10 +13,15 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 CELLS = os.path.join("benchmarks", "cells")  # this directory, from the root
+# traffic kinds whose driver file has another name; any other kind ``k`` is
+# run by ``<k>.py``
+DRIVER_ALIASES = {"open_loop": "serve"}
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
 
 @dataclasses.dataclass
@@ -65,11 +71,27 @@ def traffic(name: str, root: str = ROOT) -> dict:
     return load_json(os.path.join(root, CELLS, "traffic", f"{name}.json"))
 
 
-def layer_reader(metric: str, root: str = ROOT):
-    """The ``read(run)`` function of ``layers/<metric>.py``."""
-    path = os.path.join(root, CELLS, "layers", f"{metric}.py")
+def _load(prefix: str, name: str, path: str):
     spec = importlib.util.spec_from_file_location(
-        "layer_" + metric.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def layer_reader(metric: str, root: str = ROOT):
+    """The ``read(run)`` function of ``layers/<metric>.py``."""
+    return _load("layer_", metric,
+                 os.path.join(root, CELLS, "layers", f"{metric}.py")).read
+
+
+def driver(kind: str, root: str = ROOT):
+    """The module that runs a mix of traffic kind ``kind``: ``<kind>.py``
+    beside this file (``serve.py`` for ``open_loop``). It has
+    ``run(cell, seed, seconds, ctx)``."""
+    name = DRIVER_ALIASES.get(kind, kind)
+    path = os.path.join(root, CELLS, f"{name}.py")
+    if not NAME.fullmatch(kind) or not os.path.isfile(path):
+        raise KeyError(f"no driver for traffic kind {kind!r}: {path} "
+                       "does not exist")
+    return _load("driver_", name, path)

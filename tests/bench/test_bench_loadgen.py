@@ -75,3 +75,13 @@ def test_data_from_seed():
     # streams of one seed are independent: the check's places are not the data
     xc, _ = loadgen.observe(conf, loadgen.rng(BIG, "check"), 100)
     assert not np.array_equal(x1, xc)
+
+
+def test_round_streams_from_seed():
+    """A stream keyed by a round's index: the same seed and round give the
+    same draws, other rounds and the unkeyed stream others."""
+    a = loadgen.rng(BIG, "data", 3).uniform(size=8)
+    np.testing.assert_array_equal(a, loadgen.rng(BIG, "data", 3).uniform(size=8))
+    for other in (loadgen.rng(BIG, "data", 4), loadgen.rng(BIG, "data"),
+                  loadgen.rng(BIG, "probes", 3), loadgen.rng(BIG + 1, "data", 3)):
+        assert not np.array_equal(a, other.uniform(size=8))

@@ -204,10 +204,22 @@ def test_host_readers_on_records():
     assert _reader("queue_wait_ms")(run) == pytest.approx(200.0)
 
 
-def test_traced_run_on_the_cpu(tmp_path_factory):
+def test_traced_run_on_the_cpu(tmp_path_factory, monkeypatch):
     """The harness end to end with ``--trace 1`` at tiny size: the host
     readers read the engine's spans; the device readers find no device
     planes on the CPU and give nothing."""
+    import numpy as np
+
+    import run as harness
+
+    spans = []
+    close = harness.Context.window_close
+
+    def keep(ctx):
+        close(ctx)
+        spans.extend(ctx.spans.items)
+
+    monkeypatch.setattr(harness.Context, "window_close", keep)
     root = benchtiny.tiny_root(tmp_path_factory.mktemp("cells"))
     r = benchtiny.run_cell(root, "stream-ycsb-a", 2147483659, 8.6,
                            "--rate", "6", "--trace", "1")
@@ -216,4 +228,49 @@ def test_traced_run_on_the_cpu(tmp_path_factory):
     for name in ("tick_fetch_ms", "tick_host_ms", "fence_ms", "queue_wait_ms"):
         assert m[name]["value"] > 0, name
     assert "banded_solve_ms" not in m and "rgf_sweep_ms" not in m
-    assert m["tick_ms"]["value"] >= m["tick_fetch_ms"]["value"]
+    # each query tick of the harness holds the engine's fetch of that tick;
+    # tick_fetch_ms also counts the fetches of the ticks after fences, so
+    # its median is no bound on tick_ms's
+    (w0, w1), = [(s, e) for n, s, e in spans if n == "window"]
+    ticks = [(s, e) for n, s, e in spans if n == "tick"]
+    fetches = [(s, e) for n, s, e in obs.records()
+               if n == "engine.fetch" and w0 <= s and e <= w1]
+    held = progtrace.within(ticks, fetches)
+    assert ticks and all(len(f) == 1 for f in held)
+    assert all(te - ts >= fe - fs for (ts, te), [(fs, fe)] in zip(ticks, held))
+    assert m["tick_ms"]["value"] == pytest.approx(
+        1e3 * np.median([e - s for s, e in ticks]))
+    assert m["tick_fetch_ms"]["value"] == pytest.approx(
+        1e3 * np.median([e - s for s, e in fetches]))
+
+
+def test_offline_readers(tmp_path, monkeypatch):
+    """fit_pcg_ms keeps the fit program's runs (not the variance's PCG);
+    mll_device_ms reads the log_likelihood program's runs; idle_share.fit
+    reads only an offline cell's trace."""
+    prof = tmp_path / "x.xplane.pb"
+    prof.write_bytes(b"")
+    B = "backfit.solve"
+    ops = [(1.0, 3.0, 0, B, "jit__fit_impl"), (4.0, 5.0, 0, B, "jit__fit_impl"),
+           (6.0, 9.0, 0, B, "jit_posterior_var"),
+           (10.0, 11.0, 0, "banded.logdet", "jit_log_likelihood")]
+    mods = [("jit__fit_impl", 0.5, 5.5, 0), ("jit_posterior_var", 6.0, 9.5, 0),
+            ("jit_log_likelihood", 10.0, 12.0, 0)]
+    monkeypatch.setattr(progtrace, "_profile_file", lambda cell: str(prof))
+    monkeypatch.setattr(progtrace, "program_scopes", lambda: {})
+    monkeypatch.setattr(progtrace, "read_scoped", lambda *a: (
+        ops, mods, {"traced_begin": 0.0, "traced_end": 20.0}))
+    trace = {"busy_s": 5.0, "window_s": 20.0,
+             "module_runs": [(n, e - s) for n, s, e, _ in mods]}
+    run = _run([("window", 0.0, 40.0), ("traced", 0.0, 20.0)], trace=trace,
+               name="fig5-fit-mll")
+    run.kind = "rounds"
+    assert _reader("fit_pcg_ms")(run) == pytest.approx(3000.0)
+    assert progtrace.per_run_ms(run, B) == pytest.approx(3000.0)  # both
+    assert _reader("mll_device_ms")(run) == pytest.approx(2000.0)
+    assert _reader("idle_share.fit")(run) == pytest.approx(75.0)
+    assert _reader("idle_share.serve")(run) is None
+    run.kind = "open_loop"
+    assert _reader("idle_share.fit")(run) is None
+    for name in ("fit_pcg_ms", "mll_device_ms", "idle_share.fit"):
+        assert _reader(name)(_run([("window", 0.0, 1.0)])) is None, name

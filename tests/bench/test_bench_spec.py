@@ -1,8 +1,10 @@
-"""Cells, mixes and per-layer metrics are found by name: a new file adds
-one, with no edit to the harness."""
+"""Cells, mixes, the drivers of traffic kinds and per-layer metrics are
+found by name: a new file adds one, with no edit to the harness."""
 import json
 import os
 import shutil
+
+import pytest
 
 import benchtiny
 import spec
@@ -13,7 +15,7 @@ def test_every_cell_resolves():
     for w in bench["workloads"]:
         c = spec.cell(w["name"])
         assert c.config["name"] == w["config"]
-        assert c.traffic["kind"] == "open_loop"
+        assert callable(spec.driver(c.traffic["kind"]).run)
         names = {m["name"] for m in c.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert c.per_layer, w["name"]
@@ -75,3 +77,61 @@ def test_new_files_add_a_cell_and_a_metric(tmp_path):
     # the existing cells are untouched by the addition
     assert [m["name"] for m in spec.cell("stream-ycsb-b", root=str(root)).per_layer] \
         == [m["name"] for m in spec.cell("stream-ycsb-b").per_layer]
+
+
+def test_a_traffic_kind_is_a_driver_file(tmp_path):
+    """A mix whose kind has no alias runs ``<kind>.py`` beside the harness;
+    ``open_loop`` runs ``serve.py``; a kind with no file is refused."""
+    root = tmp_path
+    shutil.copytree(benchtiny.CELLS, root / "benchmarks" / "cells")
+    (root / "benchmarks/cells/echo.py").write_text(
+        "def run(cell, seed, seconds, ctx):\n"
+        "    ctx.window_open()\n"
+        "    ctx.window_close()\n"
+        "    return {'e2e': {'echo_s': seconds + seed}, 'attempted': 3,\n"
+        "            'failed': 0, 'checks': [('gap', 0.0, 1.0)],\n"
+        "            'complete': True}\n")
+    (root / "benchmarks/cells/traffic/echo-mix.json").write_text(json.dumps(
+        {"kind": "echo", "about": "a mix of a new kind"}))
+    bench = spec.benchmark()
+    bench["workloads"].append({"name": "echo-cell", "config": bench["configs"][0]["name"],
+                               "traffic": "echo-mix", "chips": 1, "why": "x"})
+    bench["end_to_end"].append({"name": "echo_s", "unit": "s", "better": "lower",
+                                "bound": 0.1, "source": "host_clock",
+                                "workloads": ["echo-cell"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    for c in bench["configs"]:
+        os.makedirs(root / os.path.dirname(c["file"]), exist_ok=True)
+        shutil.copy(os.path.join(benchtiny.REPO, c["file"]), root / c["file"])
+
+    r = benchtiny.run_cell(str(root), "echo-cell", 2, 1.5)
+    assert r["correct"] and r["attempted"] == 3
+    assert r["metrics"]["echo_s"] == {"value": 3.5, "unit": "s"}
+    assert set(r["metrics"]) == {"echo_s", "setup_s"}
+    assert spec.driver("echo", str(root)).__file__ == str(
+        root / "benchmarks/cells/echo.py")
+    assert spec.driver("open_loop", str(root)).__file__.endswith(
+        os.path.join("cells", "serve.py"))
+    for bad in ("no_such_kind", "../cells/serve", ""):
+        with pytest.raises(KeyError):
+            spec.driver(bad, str(root))
+
+
+def test_pending_cells_resolve(tmp_path):
+    """The cells kept out of BENCHMARK.json for now resolve from their own
+    files, with a driver, a reader per metric, and every metric moving an
+    end-to-end metric the cell reports, as a BENCHMARK.json entry must."""
+    root = benchtiny.tiny_root(tmp_path)
+    pending = spec.load_json(benchtiny.PENDING)
+    assert pending["workloads"]
+    for w in pending["workloads"]:
+        c = spec.cell(w["name"], root=root)
+        assert callable(spec.driver(c.traffic["kind"], root).run)
+        names = {m["name"] for m in c.end_to_end}
+        assert "setup_s" in names and len(names) >= 2
+        assert {m["name"] for m in c.per_layer} == {
+            m["name"] for m in pending["per_layer"]
+            if w["name"] in m["workloads"]}
+        for m in c.per_layer:
+            assert m["moves"] in names
+            assert callable(spec.layer_reader(m["name"], root))
